@@ -24,12 +24,41 @@ import numpy as np
 from repro.config import ModelConfig
 
 # ---------------------------------------------------------------------------
-# Hardware constants (TPU v5e target; the container never executes these)
+# Hardware peaks, keyed by ``jax.Device.device_kind``
 # ---------------------------------------------------------------------------
 
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+
+@dataclass(frozen=True)
+class PeakRates:
+    """Published per-chip peaks of one accelerator kind."""
+    flops: float                 # bf16 FLOP/s
+    hbm_bw: float                # HBM bytes/s
+    ici_bw: float                # bytes/s per chip-to-chip link
+    source: str
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s of interconnect per chip over 4 links (50 GB/s each).
+PEAK_RATES = {
+    "TPU v5 lite": PeakRates(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                             source='Google Cloud docs, "TPU v5e"'),
+}
+
+# The chip the planner's cost model and the dry-run roofline price for.
+TARGET_KIND = "TPU v5 lite"
+
+
+def peak_rates(kind: str) -> PeakRates:
+    """Peaks of ``kind``; a kind missing from the table is an error, never
+    a default."""
+    try:
+        return PEAK_RATES[kind]
+    except KeyError:
+        raise KeyError(f"no published peak rates for device kind {kind!r}; "
+                       f"known: {sorted(PEAK_RATES)}") from None
+
+
+TARGET = peak_rates(TARGET_KIND)
 BYTES_PER_PARAM = 2          # bf16 serving
 
 # Efficiency knobs for the serving cost model (matmul-shaped work doesn't hit

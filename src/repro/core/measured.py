@@ -18,8 +18,8 @@ import jax
 import numpy as np
 
 from repro.config import ModelConfig
-from repro.core.costmodel import (LayerCosts, PEAK_FLOPS, HBM_BW,
-                                  COMPUTE_EFF, MEMORY_EFF, BYTES_PER_PARAM)
+from repro.core.costmodel import (LayerCosts, TARGET, COMPUTE_EFF,
+                                  MEMORY_EFF, BYTES_PER_PARAM)
 from repro.models import fragment_forward, n_fragment_units, make_extras
 
 
@@ -60,8 +60,8 @@ def measure_layer_costs(cfg: ModelConfig, params, *, seq_len: int = 16,
     b0, b1 = batches[0], batches[-1]
     beta = np.maximum((lat[-1] - lat[0]) / max(b1 - b0, 1), 1e-9)
     alpha = np.maximum(lat[0] - beta * b0, 1e-9)
-    flops = beta * PEAK_FLOPS * COMPUTE_EFF
-    weights = alpha * HBM_BW * MEMORY_EFF
+    flops = beta * TARGET.flops * COMPUTE_EFF
+    weights = alpha * TARGET.hbm_bw * MEMORY_EFF
     act = np.full(L + 1, float(seq_len * cfg.d_model * BYTES_PER_PARAM))
     act[0] = seq_len * 4.0
     mobile = flops * mobile_slowdown

@@ -22,11 +22,11 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core.costmodel import (LayerCosts, PEAK_FLOPS, HBM_BW,
-                                  COMPUTE_EFF, MEMORY_EFF)
+from repro.core.costmodel import (LayerCosts, TARGET, COMPUTE_EFF,
+                                  MEMORY_EFF)
 
-CF = PEAK_FLOPS * COMPUTE_EFF
-CM = HBM_BW * MEMORY_EFF
+CF = TARGET.flops * COMPUTE_EFF
+CM = TARGET.hbm_bw * MEMORY_EFF
 
 # name: (n_layers, server_ms @ share .30 batch 1, nano_ms, tx2_ms,
 #        crossover batch, act profile)

@@ -20,9 +20,8 @@ from typing import Optional
 
 import numpy as np
 
-from repro.core.costmodel import (LayerCosts, PEAK_FLOPS, HBM_BW,
-                                  COMPUTE_EFF, MEMORY_EFF,
-                                  INSTANCE_OVERHEAD_MS)
+from repro.core.costmodel import (LayerCosts, TARGET, COMPUTE_EFF,
+                                  MEMORY_EFF, INSTANCE_OVERHEAD_MS)
 
 MAX_BATCH = 64
 SHARES = np.arange(1, 101)               # 1% resource units
@@ -53,8 +52,8 @@ class PerfProfile:
 
     def __init__(self, costs: LayerCosts):
         self.costs = costs
-        self.cf = PEAK_FLOPS * COMPUTE_EFF
-        self.cm = HBM_BW * MEMORY_EFF
+        self.cf = TARGET.flops * COMPUTE_EFF
+        self.cm = TARGET.hbm_bw * MEMORY_EFF
         self._cumF = costs.cum_flops
         self._cumW = costs.cum_weight_bytes
         self._alloc_cache: dict = {}

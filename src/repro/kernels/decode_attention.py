@@ -8,6 +8,11 @@ grid step does a (group x bk) x (bk x hd) matmul rather than a vector op.
 
 Ring-buffer caches are supported via an explicit kv_pos input: slots with
 kv_pos == -1 (unwritten) or kv_pos > q_pos are masked.
+
+Layouts follow the TPU tiling rule (the last two block dims are multiples
+of (8, 128) or span the whole array dims): the per-row query positions
+ride scalar prefetch into SMEM, and kv_pos is viewed as (B, 1, Sk) so its
+(1, bk) tile is legal at any batch size.
 """
 from __future__ import annotations
 
@@ -30,6 +35,7 @@ def _decode_kernel(qpos_ref, kvpos_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *,
                    scale: float, window: int, bk: int):
     ki = pl.program_id(2)
+    qpos = qpos_ref[pl.program_id(0)]                      # scalar (SMEM)
 
     @pl.when(ki == 0)
     def _init():
@@ -40,15 +46,14 @@ def _decode_kernel(qpos_ref, kvpos_ref, q_ref, k_ref, v_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)                    # (group, hd)
     k = k_ref[0, 0].astype(jnp.float32)                    # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32)                    # (bk, hd)
-    qpos = qpos_ref[0]                                     # scalar int32
-    kpos = kvpos_ref[0]                                    # (bk,) int32
+    kpos = kvpos_ref[0]                                    # (1, bk) int32
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     valid = (kpos >= 0) & (kpos <= qpos)
     if window:
         valid &= (qpos - kpos) < window
-    s = jnp.where(valid[None, :], s, NEG_INF)              # (group, bk)
+    s = jnp.where(valid, s, NEG_INF)                       # (group, bk)
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -90,23 +95,28 @@ def decode_attention(q: Array, k: Array, v: Array,
     vt = v.transpose(0, 2, 1, 3)
     grid = (B, KV, Sk // bk)
 
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, window=window, bk=bk),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, j: (b,)),
-            pl.BlockSpec((1, bk), lambda b, h, j: (b, j)),
-            pl.BlockSpec((1, 1, group, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, h, j, qp: (b, 0, j)),
+            pl.BlockSpec((1, 1, group, hd), lambda b, h, j, qp: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, j, qp: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, j, qp: (b, h, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, hd), lambda b, h, j: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, group, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, group, hd),
+                               lambda b, h, j, qp: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, hd), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, window=window, bk=bk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, group, hd), q.dtype),
         interpret=interpret,
-    )(q_pos.astype(jnp.int32), kv_pos.astype(jnp.int32), qt, kt, vt)
+    )(q_pos.astype(jnp.int32), kv_pos.astype(jnp.int32).reshape(B, 1, Sk),
+      qt, kt, vt)
     return out.reshape(B, 1, H, hd)

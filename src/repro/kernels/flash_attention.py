@@ -70,9 +70,7 @@ def _attn_kernel(*refs, scale: float, causal: bool, window: int,
         if window:
             mask &= (qpos - kpos) < window
         if has_seg:
-            sq = sq_ref[0, :]                          # (bq,) int32
-            sk = sk_ref[0, :]                          # (bk,) int32
-            mask &= sq[:, None] == sk[None, :]
+            mask &= sq_ref[0] == sk_ref[0]             # (bq, 1) == (1, bk)
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[...]                                # (bq, 1)
@@ -95,7 +93,8 @@ def _attn_kernel(*refs, scale: float, causal: bool, window: int,
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "scale", "block_q", "block_k", "interpret"))
 def flash_attention(q: Array, k: Array, v: Array,
-                    segment_ids: Optional[Array] = None, *,
+                    segment_ids: Optional[Array] = None,
+                    kv_segment_ids: Optional[Array] = None, *,
                     causal: bool = True, window: int = 0,
                     scale: Optional[float] = None,
                     block_q: int = DEFAULT_BQ, block_k: int = DEFAULT_BK,
@@ -104,9 +103,11 @@ def flash_attention(q: Array, k: Array, v: Array,
 
     Positions are implicit (q token i is global position i) — the prefill case.
 
-    segment_ids (B, S) int32 (self-attention, Sq == Sk): sequence-packed
-    batches — scores are masked to segment equality so packed requests
-    never attend across each other. Pad tokens carry their own id.
+    segment_ids (B, Sq) int32: sequence-packed batches — scores are masked
+    to segment equality so packed requests never attend across each other.
+    Pad tokens carry their own id. ``kv_segment_ids`` (B, Sk) defaults to
+    ``segment_ids`` (self-attention); passing both lets a caller mask
+    padded keys when Sq != Sk.
     """
     B, Sq, H, hd = q.shape
     Sk, KV = k.shape[1], k.shape[2]
@@ -124,6 +125,8 @@ def flash_attention(q: Array, k: Array, v: Array,
     group = H // KV
 
     has_seg = segment_ids is not None
+    if kv_segment_ids is None:
+        kv_segment_ids = segment_ids
     in_specs = [
         pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
         pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, h // group, j, 0)),
@@ -131,12 +134,12 @@ def flash_attention(q: Array, k: Array, v: Array,
     ]
     operands = [qt, kt, vt]
     if has_seg:
-        assert Sq == Sk, "segment_ids require self-attention (Sq == Sk)"
-        seg = segment_ids.astype(jnp.int32)
-        # the same (B, S) array feeds a q-block view and a k-block view
-        in_specs += [pl.BlockSpec((1, bq), lambda b, h, i, j: (b, i)),
-                     pl.BlockSpec((1, bk), lambda b, h, i, j: (b, j))]
-        operands += [seg, seg]
+        # q ids as a (bq, 1) column, kv ids as a (1, bk) row: both tiles
+        # span a whole unit dim, so they are legal at any B and block size
+        in_specs += [pl.BlockSpec((1, bq, 1), lambda b, h, i, j: (b, i, 0)),
+                     pl.BlockSpec((1, 1, bk), lambda b, h, i, j: (b, 0, j))]
+        operands += [segment_ids.astype(jnp.int32).reshape(B, Sq, 1),
+                     kv_segment_ids.astype(jnp.int32).reshape(B, 1, Sk)]
 
     out = pl.pallas_call(
         functools.partial(_attn_kernel, scale=scale, causal=causal,

@@ -1,17 +1,21 @@
 """Dispatching wrappers over the Pallas kernels and their jnp references.
 
-The models call these entry points; the implementation is selected by
-``set_default_impl`` / the ``impl=`` kwarg:
+The models call these entry points. The implementation is chosen once per
+process from the backend — ``pallas`` on TPU, ``reference`` elsewhere —
+and ``use_impl`` / the ``impl=`` kwarg override it explicitly (tests):
 
   * ``reference``         — chunked pure-jnp (CPU execution, dry-run lowering)
   * ``pallas``            — compiled Pallas TPU kernel (the deployment target)
   * ``pallas_interpret``  — Pallas kernel body interpreted on CPU (tests)
   * ``naive``             — full-materialisation oracle (small tests only)
+
+The choice is process-wide, not per thread: the server's pool-driver
+threads trace the pool programs, and they must see the same kernels as
+the thread that started them.
 """
 from __future__ import annotations
 
 import contextlib
-import threading
 from typing import Optional
 
 import jax
@@ -19,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention_bwd import flash_attention_trainable
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.rwkv6_scan import wkv6_scan
 from repro.kernels.ssm_scan import ssm_scan
@@ -27,26 +32,30 @@ Array = jax.Array
 
 IMPLS = ("reference", "pallas", "pallas_interpret", "naive")
 
-_state = threading.local()
+_override: Optional[str] = None
 
 
-def set_default_impl(impl: str) -> None:
-    assert impl in IMPLS, impl
-    _state.impl = impl
+def backend_impl() -> str:
+    """The implementation this process's backend runs: Pallas kernels on
+    TPU, the jnp references everywhere else."""
+    return "pallas" if jax.default_backend() == "tpu" else "reference"
 
 
 def get_default_impl() -> str:
-    return getattr(_state, "impl", "reference")
+    return _override or backend_impl()
 
 
 @contextlib.contextmanager
 def use_impl(impl: str):
-    prev = get_default_impl()
-    set_default_impl(impl)
+    """Override the backend's choice, process-wide, inside the context."""
+    global _override
+    if impl not in IMPLS:
+        raise ValueError(f"unknown kernel impl {impl!r}; known: {IMPLS}")
+    prev, _override = _override, impl
     try:
         yield
     finally:
-        set_default_impl(prev)
+        _override = prev
 
 
 def _resolve(impl: Optional[str]) -> str:
@@ -77,20 +86,23 @@ def attention(q: Array, k: Array, v: Array, *,
                                       seg_ids=seg_ids, scale=scale)
     interp = impl == "pallas_interpret"
     Sq, Sk = q.shape[1], k.shape[1]
-    bq = _pick_block(Sq, 256)
-    bk = _pick_block(Sk, 256)
-    if seg_ids is not None:
-        # packed serving path: forward-only flash kernel with the segment
-        # mask (the custom_vjp trainable variant has no segment operand —
-        # packed execution is inference, nothing differentiates it)
-        return flash_attention(q, k, v, seg_ids, causal=causal,
-                               window=window, scale=scale,
-                               block_q=bq, block_k=bk, interpret=interp)
-    # the trainable (custom_vjp) variant so jax.grad flows through the
-    # Pallas fwd/bwd kernels rather than failing to differentiate pallas_call
-    from repro.kernels.flash_attention_bwd import flash_attention_trainable
-    return flash_attention_trainable(q, k, v, causal, window, scale,
-                                     bq, bk, interp)
+    bq, Sq_p = _seq_tile(Sq, 256)
+    bk, Sk_p = _seq_tile(Sk, 256)
+    q_p, k_p, v_p = _pad_seq(q, Sq_p), _pad_seq(k, Sk_p), _pad_seq(v, Sk_p)
+    if seg_ids is None:
+        # custom_vjp entry: undifferentiated it runs the forward-only
+        # kernel; under jax.grad the Pallas backward kernels run. Keys
+        # past Sk are masked, so the sliced result and its grads are exact
+        out = flash_attention_trainable(q_p, k_p, v_p, causal, window, scale,
+                                        bq, bk, interp, Sk)
+        return out[:, :Sq]
+    # packed (serving only, forward only): padded tails carry segment id
+    # -1, so real queries never see a pad key
+    out = flash_attention(
+        q_p, k_p, v_p, _pad_seq(seg_ids, Sq_p, -1),
+        _pad_seq(seg_ids, Sk_p, -1), causal=causal, window=window,
+        scale=scale, block_q=bq, block_k=bk, interpret=interp)
+    return out[:, :Sq]
 
 
 def attend_cache(q: Array, k: Array, v: Array, q_pos: Array, kv_pos: Array, *,
@@ -106,8 +118,9 @@ def attend_cache(q: Array, k: Array, v: Array, q_pos: Array, kv_pos: Array, *,
                                   kv_pos=kv_pos, causal=True, window=window,
                                   scale=scale)
     interp = impl == "pallas_interpret"
-    bk = _pick_block(k.shape[1], 512)
-    return decode_attention(q, k, v, q_pos, kv_pos, window=window,
+    bk, Sk_p = _seq_tile(k.shape[1], 512)
+    return decode_attention(q, _pad_seq(k, Sk_p), _pad_seq(v, Sk_p), q_pos,
+                            _pad_seq(kv_pos, Sk_p, -1), window=window,
                             scale=scale, block_k=bk, interpret=interp)
 
 
@@ -165,6 +178,30 @@ def ssm_step(x, dt, A, Bm, Cm, state):
     h = a[..., None, None] * state + (dtt[..., None] * xt)[..., None] * bt[:, None, None, :]
     y = jnp.einsum("bhdn,bn->bhd", h, ct)
     return y[:, None].astype(x.dtype), h
+
+
+def _seq_tile(size: int, preferred: int) -> tuple[int, int]:
+    """(tile, padded length) for a Pallas TPU sequence axis. The compiler
+    accepts a tile that spans the whole axis or is a multiple of 128 that
+    divides it, so an axis longer than ``preferred`` (itself a multiple of
+    128) is padded to a multiple of 128 and tiled by the largest such
+    divisor not above ``preferred``."""
+    if size <= preferred:
+        return size, size
+    padded = -(-size // 128) * 128
+    tile = preferred
+    while padded % tile:
+        tile -= 128
+    return tile, padded
+
+
+def _pad_seq(x: Array, length: int, value=0) -> Array:
+    """Pad axis 1 of ``x`` up to ``length`` with ``value``."""
+    if x.shape[1] == length:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[1] = (0, length - x.shape[1])
+    return jnp.pad(x, pad, constant_values=value)
 
 
 def _pick_block(size: int, preferred: int) -> int:

@@ -119,8 +119,6 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             compiled = lowered.compile()
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):      # older jax: list of dicts
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
         if save_hlo:
             with open(save_hlo, "w") as f:

@@ -1,10 +1,11 @@
 """Roofline analysis from the compiled dry-run artifact.
 
-Three terms per (arch x shape x mesh), in seconds:
+Three terms per (arch x shape x mesh), in seconds, priced at the peaks of
+``core.costmodel.TARGET_KIND`` from the one table of published rates:
 
-  compute    = FLOPs / (chips x 197 TFLOP/s bf16)
-  memory     = HBM bytes / (chips x 819 GB/s)
-  collective = collective bytes / (chips x 50 GB/s per ICI link)
+  compute    = FLOPs / (chips x peak FLOP/s)
+  memory     = HBM bytes / (chips x HBM bytes/s)
+  collective = collective bytes / (chips x ICI bytes/s per link)
 
 FLOPs/bytes come from two sources that are cross-checked:
   * ``compiled.cost_analysis()`` — exact for straight-line HLO, but counts
@@ -24,9 +25,7 @@ import math
 import re
 from dataclasses import dataclass, field
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+from repro.core.costmodel import TARGET
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -252,15 +251,15 @@ class Roofline:
 
     @property
     def compute_s(self) -> float:
-        return self.flops / (self.chips * PEAK_FLOPS)
+        return self.flops / (self.chips * TARGET.flops)
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / (self.chips * HBM_BW)
+        return self.hbm_bytes / (self.chips * TARGET.hbm_bw)
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / (self.chips * ICI_BW)
+        return self.collective_bytes / (self.chips * TARGET.ici_bw)
 
     @property
     def dominant(self) -> str:

@@ -19,6 +19,7 @@ attainment, p50/p99 latency, and the replan count.
 from __future__ import annotations
 
 import argparse
+from pathlib import Path
 
 import numpy as np
 
@@ -167,6 +168,9 @@ def main(argv=None):
                          "autoregressive, generating this many tokens "
                          "per request (0 = all one-shot)")
     args = ap.parse_args(argv)
+    # run from the checkout root, like every command in the README
+    from repro.serving.smoke import configure_compile_cache
+    configure_compile_cache(Path.cwd())
 
     if args.serve_loop:
         return run_serve_loop_cli(args)

@@ -121,8 +121,11 @@ def packed_fragment_fn(cfg: ModelConfig, depth: int, embed: bool,
     """The cached jitted packed program for any fragment of ``depth``
     blocks. Call as ``fn(params, inputs, seg_ids, positions, start)``
     with ``inputs`` (1, T) int32 token ids when ``embed`` else
-    (1, T, d) hidden states."""
-    key = _cfg_key(cfg) + (int(depth), bool(embed), bool(head))
+    (1, T, d) hidden states. The kernel implementation in force is part
+    of the key: a trace under one never serves a call under another."""
+    from repro.kernels.ops import get_default_impl
+    key = _cfg_key(cfg) + (int(depth), bool(embed), bool(head),
+                           get_default_impl())
     fn = _PACKED_FNS.get(key)
     if fn is None:
         fn = _PACKED_FNS[key] = jax.jit(functools.partial(

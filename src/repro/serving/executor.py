@@ -87,7 +87,7 @@ def pool_endpoint(key: tuple) -> str:
 def _extras_sig(extras: Optional[dict]) -> tuple:
     """Batchability signature of a request's extras: keys AND array
     shapes/dtypes. Requests batch together only when their extras are
-    layout-compatible — and the compile-count key includes this, so
+    layout-compatible — and the jit cache keys on the same shapes, so
     extras-shape churn is counted as the retrace it really causes."""
     if not extras:
         return ()
@@ -101,15 +101,6 @@ def _sig_tuple(x):
     if isinstance(x, (list, tuple)):
         return tuple(_sig_tuple(e) for e in x)
     return x
-
-
-def _jit_cache_size(fn) -> Optional[int]:
-    """Number of compiled entries in a jitted function's cache, or None
-    when the jax version doesn't expose it."""
-    try:
-        return int(fn._cache_size())
-    except Exception:
-        return None
 
 
 class FragmentInstance:
@@ -161,7 +152,6 @@ class FragmentInstance:
         self.n_compiles = 0
         self.real_tokens = 0          # payload tokens actually requested
         self.pad_tokens = 0           # bucket-padding tokens executed
-        self._shapes_seen: set = set()
         # -- decode (autoregressive) serving state, built lazily on the
         # first admission so one-shot pools pay nothing --
         self.decode_ctx = int(decode_ctx)
@@ -233,20 +223,12 @@ class FragmentInstance:
                     out.extend(self._run_padded(sig, grp))
         return out
 
-    def _call_counted(self, fn, *args, shape_key, **kwargs):
+    def _call_counted(self, fn, *args, **kwargs):
         """Invoke a jitted program, counting ACTUAL compile events via
-        the jit cache-size delta (falls back to first-sighting of the
-        full shape key — which includes extras shapes/dtypes — when the
-        jax version hides the cache)."""
-        before = _jit_cache_size(fn)
+        the jit cache-size delta."""
+        before = fn._cache_size()
         y = fn(*args, **kwargs)
-        after = _jit_cache_size(fn)
-        if before is not None and after is not None:
-            self.n_compiles += max(after - before, 0)
-            self._shapes_seen.add(shape_key)
-        elif shape_key not in self._shapes_seen:
-            self._shapes_seen.add(shape_key)
-            self.n_compiles += 1
+        self.n_compiles += max(fn._cache_size() - before, 0)
         return y
 
     def _run_packed(self, grp: list) -> list:
@@ -264,8 +246,7 @@ class FragmentInstance:
         t0 = time.perf_counter()
         y = self._call_counted(
             fn, self._params, cat[None], jnp.asarray(seg)[None],
-            jnp.asarray(pos)[None], np.int32(self.start),
-            shape_key=("packed", tuple(cat.shape), str(cat.dtype)))
+            jnp.asarray(pos)[None], np.int32(self.start))
         self._m_exec_ms.record((time.perf_counter() - t0) * 1e3)
         self._m_batch_tokens.record(total)
         self.n_batches += 1
@@ -298,8 +279,7 @@ class FragmentInstance:
             extras = self._stack_extras([r.extras for r, _, _ in items], tgt)
             t0 = time.perf_counter()
             y = self._call_counted(
-                self._fn, self._params, inputs=stacked, extras=extras,
-                shape_key=(tuple(stacked.shape), str(stacked.dtype), sig))
+                self._fn, self._params, inputs=stacked, extras=extras)
             self._m_exec_ms.record((time.perf_counter() - t0) * 1e3)
             self._m_batch_tokens.record(sum(S for _, _, S in items))
             self.n_batches += 1
@@ -512,8 +492,7 @@ class FragmentInstance:
             toks[i, 0] = self._slots[i]["last"]
         pos_before = np.asarray(self._dc["pos"])
         logits, self._dc = self._call_counted(
-            self._dstep, self._params, self._dc,
-            jnp.asarray(toks), shape_key=("decode", B))
+            self._dstep, self._params, self._dc, jnp.asarray(toks))
         nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
         k_np = np.asarray(self._dc["k"], np.float32)
         v_np = np.asarray(self._dc["v"], np.float32)

@@ -20,6 +20,11 @@ How the worker process starts is a pluggable :class:`WorkerLauncher`:
     prefix is injectable, which is also how tests run the launcher
     without an ssh daemon.
 
+Workers run JAX on the parent's platform. An accelerator belongs to one
+process, and a parent that has touched JAX holds it, so RemoteExecutor
+runs from a CPU parent only; on a chip host the in-process
+``GraftExecutor`` is the serving path.
+
 The accepted connection is a persistent framed request/reply channel
 (the same ``PoolService`` message vocabulary local pools speak). The
 worker builds its jitted fragment program from an ``init`` message
@@ -262,7 +267,8 @@ class WorkerLauncher:
 
 class SubprocessLauncher(WorkerLauncher):
     """Worker on THIS machine (the default): same interpreter, source
-    tree injected on PYTHONPATH, CPU jax."""
+    tree injected on PYTHONPATH, the parent's environment (and so its
+    JAX platform) inherited."""
 
     def argv(self, connect: str, max_frame_bytes: int) -> list:
         # -c instead of -m: runpy would re-execute this module on top of
@@ -277,14 +283,14 @@ class SubprocessLauncher(WorkerLauncher):
         env = dict(os.environ)
         env["PYTHONPATH"] = SRC_ROOT + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
         return {"env": env}
 
 
 class SSHLauncher(WorkerLauncher):
     """Worker on ANOTHER host: ``ssh <host> env PYTHONPATH=<remote src>
-    JAX_PLATFORMS=cpu <python> -m repro.serving.remote --connect
-    <advertise_host:port>``.
+    [JAX_PLATFORMS=<the parent's>] <python> -m repro.serving.remote
+    --connect <advertise_host:port>``. The worker inherits the parent's
+    ``JAX_PLATFORMS`` when the parent has one set.
 
     The handshake is identical to the local launcher — the parent only
     ever sees a dial-back connection, so the executor cannot tell (and
@@ -295,20 +301,18 @@ class SSHLauncher(WorkerLauncher):
 
     def __init__(self, host: str, *, python: str = "python3",
                  pythonpath: Optional[str] = SRC_ROOT,
-                 jax_platforms: Optional[str] = "cpu",
                  ssh: tuple = ("ssh",)):
         self.host = host
         self.python = python
         self.pythonpath = pythonpath
-        self.jax_platforms = jax_platforms
         self.ssh = tuple(ssh)
 
     def argv(self, connect: str, max_frame_bytes: int) -> list:
         envs = []
         if self.pythonpath:
             envs.append(f"PYTHONPATH={self.pythonpath}")
-        if self.jax_platforms:
-            envs.append(f"JAX_PLATFORMS={self.jax_platforms}")
+        if os.environ.get("JAX_PLATFORMS"):
+            envs.append(f"JAX_PLATFORMS={os.environ['JAX_PLATFORMS']}")
         remote = (["env", *envs] if envs else []) + [
             self.python, "-m", "repro.serving.remote",
             "--connect", connect, "--max-frame", str(max_frame_bytes)]
@@ -721,6 +725,14 @@ class RemoteExecutor(GraftExecutor):
                  packed: bool = True, telemetry=None,
                  beacon_interval_s: float = 0.0,
                  beacon_stale_s: Optional[float] = None):
+        import jax
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"RemoteExecutor needs a CPU parent, but this process runs "
+                f"JAX on {jax.default_backend()!r}: an accelerator belongs "
+                f"to one process, and the parent already holds it, so "
+                f"worker pools could not reach it. Serve on the chip with "
+                f"the in-process GraftExecutor.")
         self._workers: dict[tuple, WorkerProc] = {}
         self._cfg_bytes = pickle.dumps(cfg)
         self._params_np = _np_tree(params)

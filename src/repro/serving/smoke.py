@@ -10,6 +10,8 @@ so the pieces can't drift apart.
 from __future__ import annotations
 
 import dataclasses
+import os
+from pathlib import Path
 from typing import Optional
 
 import numpy as np
@@ -22,25 +24,51 @@ DEFAULT_ARCH = "qwen3-1.7b"
 DEFAULT_SEQ = 16
 
 
+def configure_compile_cache(root: Path) -> str:
+    """Point JAX's persistent compile cache at ``root/.jax_cache``, for an
+    entry point run from the checkout ``root`` (never at import, so tests
+    keep their own settings).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing else is set. The path is fixed, so each run finds what the
+    previous one wrote. Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = str(Path(root) / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def smoke_setup(arch: str = DEFAULT_ARCH, *, seq_len: int = DEFAULT_SEQ,
-                seed: int = 0, n_layers: Optional[int] = None):
+                seed: int = 0, n_layers: Optional[int] = None,
+                published: bool = False):
     """-> (cfg, book, params): everything an executor needs, smoke scale.
 
     ``n_layers`` deepens the reduced model beyond the default 2 blocks —
     multi-stage chains (align -> shared) need at least 3 boundaries to be
-    interesting."""
+    interesting. ``published`` returns the registry config itself, at its
+    published widths and dtype (``n_layers`` is then ignored): the chip
+    smoke serves that."""
     import jax
     from repro import models as M
     from repro.configs import get_config, get_smoke_config, reduced
 
-    cfg = get_smoke_config(arch)
-    if n_layers is not None and n_layers != cfg.n_layers:
-        cfg = reduced(get_config(arch), n_layers=n_layers)
+    if published:
+        cfg = get_config(arch)
+    else:
+        cfg = get_smoke_config(arch)
+        if n_layers is not None and n_layers != cfg.n_layers:
+            cfg = reduced(get_config(arch), n_layers=n_layers)
     costs = dataclasses.replace(arch_layer_costs(cfg, seq_len=seq_len),
                                 name=cfg.name)
     book = ProfileBook()
     book.add(costs)
-    params = M.init_params(jax.random.PRNGKey(seed), cfg)
+    # one compiled program for the whole init (same values as eager),
+    # so the weights are made on the device without per-op dispatch
+    params = jax.jit(M.init_params, static_argnums=1)(
+        jax.random.PRNGKey(seed), cfg)
     return cfg, book, params
 
 

@@ -47,10 +47,8 @@ from typing import Callable, Optional
 
 import numpy as np
 
-try:  # baked into the image; gate anyway so import never hard-fails
-    import msgpack
-except ImportError:  # pragma: no cover - exercised only on stripped envs
-    msgpack = None
+import ml_dtypes  # noqa: F401  registers bfloat16 & co. with numpy by name
+import msgpack
 
 __all__ = [
     "FrameError", "TruncatedFrameError", "TransferStats", "error_reply",
@@ -95,7 +93,10 @@ def _pack_default(obj):
     if isinstance(obj, np.ndarray):
         # ascontiguousarray promotes 0-d to 1-d: keep the ORIGINAL shape
         a = np.ascontiguousarray(obj)
-        return {"__nd__": 1, "dtype": a.dtype.str, "shape": list(obj.shape),
+        # extension dtypes (bfloat16) have no array-protocol code — their
+        # ``str`` is an opaque void ``<V2`` — so they travel by name
+        dt = a.dtype.name if a.dtype.kind == "V" else a.dtype.str
+        return {"__nd__": 1, "dtype": dt, "shape": list(obj.shape),
                 "data": a.tobytes()}
     if isinstance(obj, (np.generic,)):          # numpy scalars
         return obj.item()
@@ -200,17 +201,9 @@ def kv_frame_nbytes(frame: dict) -> int:
     return n + 64
 
 
-def _require_msgpack():
-    if msgpack is None:  # pragma: no cover
-        raise RuntimeError(
-            "msgpack is required for the serving transport wire format "
-            "and is not importable in this environment")
-
-
 def encode_frame(msg: dict, *, max_frame_bytes: int = DEFAULT_MAX_FRAME
                  ) -> bytes:
     """``msg`` (msgpack-able dict, ndarrays allowed) -> framed bytes."""
-    _require_msgpack()
     body = msgpack.packb(msg, default=_pack_default, use_bin_type=True)
     if len(body) > max_frame_bytes:
         raise FrameError(f"frame of {len(body)} bytes exceeds "
@@ -254,7 +247,6 @@ def _read_exact(readable, n: int) -> bytearray:
 def read_frame(readable, *, max_frame_bytes: int = DEFAULT_MAX_FRAME
                ) -> dict:
     """Read one length-prefixed frame from a socket or file-like object."""
-    _require_msgpack()
     header = _read_exact(readable, _LEN.size)
     (length,) = _LEN.unpack(header)
     if length > max_frame_bytes:
@@ -510,7 +502,6 @@ class SocketTransport(Transport):
 
     def __init__(self, *, max_frame_bytes: int = DEFAULT_MAX_FRAME,
                  host: str = "127.0.0.1"):
-        _require_msgpack()
         self.max_frame_bytes = max_frame_bytes
         self.host = host
         self._servers: dict[str, _SocketServer] = {}

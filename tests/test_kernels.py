@@ -224,3 +224,94 @@ def test_flash_trainable_through_ops():
         return jnp.sum(ops.attention(q, k, v, causal=True, impl="naive"))
     gr = jax.grad(fr)(q)
     np.testing.assert_allclose(np.asarray(g), np.asarray(gr), atol=2e-5)
+
+
+@pytest.mark.parametrize("size,preferred", [(12, 256), (256, 256),
+                                            (272, 256), (300, 256),
+                                            (512, 256), (640, 512),
+                                            (1000, 512)])
+def test_seq_tile_is_legal(size, preferred):
+    """Tiles span the whole (padded) axis or are 128-multiples dividing
+    it — the only sequence tiles the TPU compiler accepts."""
+    tile, padded = ops._seq_tile(size, preferred)
+    assert padded >= size and padded - size < 128 and padded % tile == 0
+    assert tile == padded or (tile % 128 == 0 and tile <= preferred)
+
+
+@pytest.mark.parametrize("Sq,Sk,causal,packed", [
+    (300, 300, True, False),     # unpacked prefill, padded to 384
+    (300, 300, True, True),      # packed total, padded
+    (264, 300, False, False),    # cross attention: keys padded
+])
+def test_attention_padded_tiles_exact(Sq, Sk, causal, packed):
+    """Lengths without a legal tile are padded; the pad never leaks into
+    real rows."""
+    H, KV, hd = 4, 2, 32
+    ks = jax.random.split(jax.random.PRNGKey(9), 3)
+    q = _rand(ks[0], (1, Sq, H, hd))
+    k = _rand(ks[1], (1, Sk, KV, hd))
+    v = _rand(ks[2], (1, Sk, KV, hd))
+    seg = (jnp.arange(Sq) >= 100).astype(jnp.int32)[None] if packed else None
+    got, want = (ops.attention(q, k, v, causal=causal, seg_ids=seg, impl=i)
+                 for i in ("pallas_interpret", "naive"))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=1e-3)
+
+
+@pytest.mark.parametrize("Sq,Sk,causal,window", [
+    (300, 300, True, 0),         # padded to 384, tiles of 128
+    (300, 300, True, 40),        # sliding window over the pad
+    (264, 300, False, 0),        # cross attention: pad keys masked
+])
+def test_attention_grad_padded_exact(Sq, Sk, causal, window):
+    """Lengths without a legal tile stay differentiable through the pad:
+    the Pallas backward's grads equal autodiff of the oracle."""
+    H, KV, hd = 4, 2, 32
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = _rand(ks[0], (1, Sq, H, hd), scale=0.5)
+    k = _rand(ks[1], (1, Sk, KV, hd), scale=0.5)
+    v = _rand(ks[2], (1, Sk, KV, hd), scale=0.5)
+
+    def loss(impl):
+        return lambda q, k, v: jnp.sum(jnp.sin(ops.attention(
+            q, k, v, causal=causal, window=window, impl=impl)))
+
+    got, want = (jax.grad(loss(i), argnums=(0, 1, 2))(q, k, v)
+                 for i in ("pallas_interpret", "naive"))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-5, rtol=1e-3)
+
+
+def test_attend_cache_padded_context():
+    """A decode context without a legal tile is padded with unwritten
+    (kv_pos == -1) slots."""
+    B, Sk, H, KV, hd = 3, 600, 4, 2, 32
+    ks = jax.random.split(jax.random.PRNGKey(10), 3)
+    q = _rand(ks[0], (B, 1, H, hd))
+    k = _rand(ks[1], (B, Sk, KV, hd))
+    v = _rand(ks[2], (B, Sk, KV, hd))
+    q_pos = jnp.asarray([5, 300, 599], jnp.int32)
+    kv_pos = jnp.broadcast_to(jnp.arange(Sk, dtype=jnp.int32)[None], (B, Sk))
+    kv_pos = jnp.where(kv_pos <= q_pos[:, None], kv_pos, -1)
+    got, want = (ops.attend_cache(q, k, v, q_pos, kv_pos, impl=i)
+                 for i in ("pallas_interpret", "naive"))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5)
+
+
+def test_impl_choice_is_process_wide():
+    """The backend picks the kernels; an override reaches every thread
+    (pool drivers trace their programs off the main thread)."""
+    import threading
+    assert ops.backend_impl() == "reference"          # CPU test backend
+    seen = []
+    with ops.use_impl("pallas_interpret"):
+        t = threading.Thread(target=lambda: seen.append(
+            ops.get_default_impl()))
+        t.start()
+        t.join(timeout=10)
+    assert seen == ["pallas_interpret"]
+    assert ops.get_default_impl() == "reference"
+    with pytest.raises(ValueError):
+        with ops.use_impl("cuda"):
+            pass

@@ -51,3 +51,15 @@ def test_roofline_terms():
     assert abs(r.collective_s - 1.0) < 1e-9
     assert abs(r.useful_ratio - 0.5) < 1e-9
     assert r.dominant in ("compute", "memory", "collective")
+
+
+def test_peak_rates_keyed_by_device_kind():
+    """One table of published peaks; a kind missing from it is an error."""
+    import pytest
+    from repro.core.costmodel import TARGET, TARGET_KIND, peak_rates
+    v5e = peak_rates("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw, v5e.ici_bw) == (197e12, 819e9, 50e9)
+    assert "TPU v5e" in v5e.source
+    assert peak_rates(TARGET_KIND) is TARGET
+    with pytest.raises(KeyError, match="no published peak rates"):
+        peak_rates("cpu")

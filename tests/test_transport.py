@@ -13,7 +13,7 @@ from repro.serving.transport import (
 # ------------------------------------------------------------------ framing
 
 DTYPES = ["float32", "float16", "float64", "int32", "int8", "uint8",
-          "int64", "bool", "complex64"]
+          "int64", "bool", "complex64", "bfloat16"]
 SHAPES = [(), (0,), (1,), (7,), (3, 4), (2, 3, 5), (1, 16, 256)]
 
 
