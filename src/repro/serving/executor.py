@@ -52,7 +52,7 @@ from repro.models.packed import (is_packable, pack_segments,
 from repro.serving.batcher import bucket_size, seq_bucket, token_bucket
 from repro.serving.kvcache import KVCacheOOM, PagedKVCache
 from repro.serving.simulator import _routing
-from repro.serving.telemetry import NULL as NULL_TELEMETRY
+from repro.serving.telemetry import NULL as NULL_TELEMETRY, phase
 from repro.serving.transport import (Channel, InProcessTransport, Transport,
                                      decode_kv_blocks, encode_kv_blocks,
                                      error_reply)
@@ -129,6 +129,7 @@ class FragmentInstance:
         self.owns_telemetry = False
         self._m_exec_ms = self.telemetry.histogram("pool/exec_ms")
         self._m_batch_tokens = self.telemetry.histogram("pool/batch_tokens")
+        self._m_d2h_bytes = self.telemetry.counter("pool/d2h_bytes")
         self.key = spec.key
         self.start, self.end = spec.start, spec.end
         self.batch = spec.batch
@@ -164,6 +165,7 @@ class FragmentInstance:
         self.decode_admits = 0
         self.decode_steps = 0
         self.decode_tokens = 0                # admission firsts + step emits
+        self.d2h_bytes = 0                    # decode steps' device reads
         self.prefill_exports = 0              # cross-pool KV handoffs out
         self.kv_handoffs_in = 0               # cross-pool KV handoffs in
         # cross-request prefix sharing reconstructs a prompt's KV from the
@@ -356,29 +358,35 @@ class FragmentInstance:
         token, the cache row, and the arena-bound suffix KV."""
         cfg, S = self.cfg, int(toks.shape[0])
         pop = min(n_shared, S - 1)            # prefix positions gathered
-        if pop == 0:
-            logits, c1 = prefill(self._params, cfg, jnp.asarray(toks)[None],
-                                 cache_seq=self.decode_ctx)
-        else:
-            c1 = init_cache(cfg, 1, self.decode_ctx)
+        if pop:
             k, v = self.kv.gather(rid, pop)   # (pop, L, KV, hd)
-            kk = jnp.asarray(k).transpose(1, 0, 2, 3)[:, None]
-            vv = jnp.asarray(v).transpose(1, 0, 2, 3)[:, None]
-            c1["k"] = c1["k"].at[:, :, :pop].set(kk.astype(c1["k"].dtype))
-            c1["v"] = c1["v"].at[:, :, :pop].set(vv.astype(c1["v"].dtype))
-            c1["kv_pos"] = c1["kv_pos"].at[0, :pop].set(
-                jnp.arange(pop, dtype=jnp.int32))
-            c1["pos"] = jnp.full((1,), pop, jnp.int32)
-            logits = None
-            for t in toks[pop:]:
-                logits, c1 = self._dstep(
-                    self._params, c1, jnp.asarray([[int(t)]], jnp.int32))
-        first = int(jnp.argmax(logits[0, -1]))
-        sl = np.arange(n_shared, S)           # arena-bound suffix positions
-        k_np = np.asarray(c1["k"], np.float32)
-        v_np = np.asarray(c1["v"], np.float32)
-        ks = k_np[:, 0, sl].transpose(1, 0, 2, 3)
-        vs = v_np[:, 0, sl].transpose(1, 0, 2, 3)
+        with phase("decode/prefill"):
+            if pop == 0:
+                logits, c1 = prefill(self._params, cfg,
+                                     jnp.asarray(toks)[None],
+                                     cache_seq=self.decode_ctx)
+            else:
+                c1 = init_cache(cfg, 1, self.decode_ctx)
+                kk = jnp.asarray(k).transpose(1, 0, 2, 3)[:, None]
+                vv = jnp.asarray(v).transpose(1, 0, 2, 3)[:, None]
+                c1["k"] = c1["k"].at[:, :, :pop].set(
+                    kk.astype(c1["k"].dtype))
+                c1["v"] = c1["v"].at[:, :, :pop].set(
+                    vv.astype(c1["v"].dtype))
+                c1["kv_pos"] = c1["kv_pos"].at[0, :pop].set(
+                    jnp.arange(pop, dtype=jnp.int32))
+                c1["pos"] = jnp.full((1,), pop, jnp.int32)
+                logits = None
+                for t in toks[pop:]:
+                    logits, c1 = self._dstep(
+                        self._params, c1,
+                        jnp.asarray([[int(t)]], jnp.int32))
+            first = int(jnp.argmax(logits[0, -1]))
+            sl = np.arange(n_shared, S)       # arena-bound suffix positions
+            k_np = np.asarray(c1["k"], np.float32)
+            v_np = np.asarray(c1["v"], np.float32)
+            ks = k_np[:, 0, sl].transpose(1, 0, 2, 3)
+            vs = v_np[:, 0, sl].transpose(1, 0, 2, 3)
         return first, c1, ks, vs
 
     def prefill_export(self, rid: int, client: str, tokens,
@@ -465,7 +473,8 @@ class FragmentInstance:
         if done:
             self.kv.finish(rid, retain=self._kv_share)
         else:
-            self._dc = self._copy_row(self._dc, c1, slot)
+            with phase("decode/row_copy"):
+                self._dc = self._copy_row(self._dc, c1, slot)
             self._slots[slot] = {"rid": rid, "client": client,
                                  "max_new": max_new, "n_gen": 1,
                                  "last": first, "out": [first],
@@ -487,15 +496,19 @@ class FragmentInstance:
             return {"events": [], "active": 0,
                     "free_slots": len(self._slots)}
         B = len(self._slots)
-        toks = np.zeros((B, 1), np.int32)
-        for i in active:
-            toks[i, 0] = self._slots[i]["last"]
-        pos_before = np.asarray(self._dc["pos"])
-        logits, self._dc = self._call_counted(
-            self._dstep, self._params, self._dc, jnp.asarray(toks))
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-        k_np = np.asarray(self._dc["k"], np.float32)
-        v_np = np.asarray(self._dc["v"], np.float32)
+        with phase("decode/dispatch"):
+            toks = np.zeros((B, 1), np.int32)
+            for i in active:
+                toks[i, 0] = self._slots[i]["last"]
+            pos_before = np.asarray(self._dc["pos"])
+            logits, self._dc = self._call_counted(
+                self._dstep, self._params, self._dc, jnp.asarray(toks))
+        with phase("decode/sync"):
+            nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+        k_np = self._read_back(self._dc["k"])
+        v_np = self._read_back(self._dc["v"])
+        n_read = (pos_before.nbytes + nxt.nbytes + self._dc["k"].nbytes
+                  + self._dc["v"].nbytes)
         events = []
         for i in active:
             s = self._slots[i]
@@ -527,9 +540,20 @@ class FragmentInstance:
             events.append(ev)
         self.decode_steps += 1
         self.decode_tokens += len(active)
+        self.d2h_bytes += n_read
+        self._m_d2h_bytes.inc(n_read)
         return {"events": events,
                 "active": sum(1 for s in self._slots if s),
                 "free_slots": sum(1 for s in self._slots if s is None)}
+
+    @staticmethod
+    def _read_back(x) -> np.ndarray:
+        """Device -> host copy of a decode-cache array at its own dtype,
+        then its widening to the arena's float32."""
+        with phase("decode/readback", bytes=x.nbytes):
+            h = np.asarray(x)
+        with phase("decode/widen"):
+            return h.astype(np.float32, copy=False)
 
     def decode_abort(self, rid: int) -> bool:
         """Evict one resident sequence (mid-decode shed): free its KV
@@ -698,6 +722,7 @@ class PoolService:
                     "decode_admits": inst.decode_admits,
                     "decode_steps": inst.decode_steps,
                     "decode_tokens": inst.decode_tokens,
+                    "d2h_bytes": inst.d2h_bytes,
                     "prefill_exports": inst.prefill_exports,
                     "kv_handoffs_in": inst.kv_handoffs_in,
                     "kv": inst.kv.stats() if inst.kv else None,

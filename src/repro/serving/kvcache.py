@@ -37,7 +37,7 @@ from typing import Optional
 
 import numpy as np
 
-from repro.serving.telemetry import NULL as NULL_TELEMETRY
+from repro.serving.telemetry import NULL as NULL_TELEMETRY, phase
 
 
 class KVCacheOOM(RuntimeError):
@@ -265,7 +265,8 @@ class PagedKVCache:
         n = seq.prompt_len - seq.n_shared
         if ks.shape[0] != n:
             raise ValueError(f"expected {n} suffix tokens, got {ks.shape[0]}")
-        self._write_at(seq, seq.n_shared, ks, vs)
+        with phase("kv/write_prompt"):
+            self._write_at(seq, seq.n_shared, ks, vs)
 
     def _write_at(self, seq: _Seq, pos0: int, ks, vs) -> None:
         bt = self.block_tokens
@@ -299,39 +300,41 @@ class PagedKVCache:
                ) -> None:
         """Append one generated token's KV. Allocates at block
         boundaries; COWs a shared partial block before writing."""
-        seq = self._seqs[rid]
-        bt = self.block_tokens
-        if seq.n_tokens % bt == 0:                     # boundary: new block
-            blk = self._alloc_block()
-            seq.blocks.append(blk)
-        else:
-            blk = self._writable_last(seq)
-        slot = seq.n_tokens % bt
-        self._k[blk.idx, slot] = np.asarray(k, np.float32)
-        self._v[blk.idx, slot] = np.asarray(v, np.float32)
-        blk.tokens = blk.tokens + (int(token),)
-        blk.filled += 1
-        seq.n_tokens += 1
-        self._touch(blk)
+        with phase("kv/append"):
+            seq = self._seqs[rid]
+            bt = self.block_tokens
+            if seq.n_tokens % bt == 0:                 # boundary: new block
+                blk = self._alloc_block()
+                seq.blocks.append(blk)
+            else:
+                blk = self._writable_last(seq)
+            slot = seq.n_tokens % bt
+            self._k[blk.idx, slot] = np.asarray(k, np.float32)
+            self._v[blk.idx, slot] = np.asarray(v, np.float32)
+            blk.tokens = blk.tokens + (int(token),)
+            blk.filled += 1
+            seq.n_tokens += 1
+            self._touch(blk)
 
     def gather(self, rid: int, n: Optional[int] = None
                ) -> tuple[np.ndarray, np.ndarray]:
         """KV for the sequence's first ``n`` tokens as (n, L, KV, hd)."""
-        seq = self._seqs[rid]
-        n = seq.n_tokens if n is None else n
-        bt = self.block_tokens
-        ks, vs, got = [], [], 0
-        for blk in seq.blocks:
-            if got >= n:
-                break
-            take = min(blk.filled, bt, n - got)
-            ks.append(self._k[blk.idx, :take])
-            vs.append(self._v[blk.idx, :take])
-            got += take
-        if got < n:
-            raise ValueError(f"sequence {rid}: asked {n} tokens, "
-                             f"only {got} resident")
-        return np.concatenate(ks, axis=0), np.concatenate(vs, axis=0)
+        with phase("kv/gather"):
+            seq = self._seqs[rid]
+            n = seq.n_tokens if n is None else n
+            bt = self.block_tokens
+            ks, vs, got = [], [], 0
+            for blk in seq.blocks:
+                if got >= n:
+                    break
+                take = min(blk.filled, bt, n - got)
+                ks.append(self._k[blk.idx, :take])
+                vs.append(self._v[blk.idx, :take])
+                got += take
+            if got < n:
+                raise ValueError(f"sequence {rid}: asked {n} tokens, "
+                                 f"only {got} resident")
+            return np.concatenate(ks, axis=0), np.concatenate(vs, axis=0)
 
     def finish(self, rid: int, *, retain: bool = True) -> None:
         """Complete a sequence. Prompt blocks whose content still matches

@@ -55,7 +55,7 @@ from repro.serving.batcher import (BatchItem, MicroBatcher, ShedPolicy,
 from repro.serving.executor import (GraftExecutor, PoolDrainingError,
                                     ServeRequest)
 from repro.serving.telemetry import (Histogram, NULL as NULL_TELEMETRY,
-                                     Telemetry)
+                                     Telemetry, phase)
 
 __all__ = ["GraftServer", "PoolDriver", "run_serve_loop",
            "summarize_records"]
@@ -1003,40 +1003,43 @@ class GraftServer:
         ``decode_continuous`` off this degrades to the waved baseline:
         new admissions wait until the whole batch drains."""
         handle = self._pool_handle(driver.key)
-        foreign = None
-        if driver.decode_free > 0 and (self.decode_continuous
-                                       or driver.decode_active == 0):
-            items = driver.batcher.take(driver.decode_free)
-            oneshot = [it for it in items if not it.decode]
-            for it in items:
-                if it.decode:
-                    self._decode_admit(driver, handle, it)
-            if oneshot:
-                # a mixed pool: taken one-shot items run as a normal
-                # batch between decode steps
-                foreign = self._run_batch(driver, oneshot)
+        foreign, items = None, []
+        with phase("server/decode_tick"):
+            if driver.decode_free > 0 and (self.decode_continuous
+                                           or driver.decode_active == 0):
+                items = driver.batcher.take(driver.decode_free)
+        oneshot = [it for it in items if not it.decode]
+        for it in items:
+            if it.decode:
+                self._decode_admit(driver, handle, it)
+        if oneshot:
+            # a mixed pool: taken one-shot items run as a normal batch
+            # between decode steps
+            foreign = self._run_batch(driver, oneshot)
         if driver.decode_active == 0:
             return foreign
         t0 = self._perf()
         rep = handle.decode_step()
-        driver.note_decode_step(self._perf() - t0)
-        now = self.now_ms()
-        for ev in rep.get("events", []):
-            st = self._inflight.get(ev["rid"])
-            if st is None:
-                continue
-            st.n_gen = int(ev.get("n_gen", st.n_gen))
-            if not ev.get("done"):
-                continue
-            driver.decode_resident.pop(ev["rid"], None)
-            if ev.get("oom"):
-                # the arena ran out mid-stream and the pool force-closed
-                # the sequence — account it as a shed, not a completion
-                self._shed(ev["rid"], st, "decode")
-            else:
-                self._complete_decode(ev["rid"], st, ev["tokens"])
-        driver.decode_active = int(rep.get("active", 0))
-        driver.decode_free = int(rep.get("free_slots", driver.decode_free))
+        with phase("server/decode_tick"):
+            driver.note_decode_step(self._perf() - t0)
+            now = self.now_ms()
+            for ev in rep.get("events", []):
+                st = self._inflight.get(ev["rid"])
+                if st is None:
+                    continue
+                st.n_gen = int(ev.get("n_gen", st.n_gen))
+                if not ev.get("done"):
+                    continue
+                driver.decode_resident.pop(ev["rid"], None)
+                if ev.get("oom"):
+                    # the arena ran out mid-stream and the pool force-
+                    # closed the sequence — a shed, not a completion
+                    self._shed(ev["rid"], st, "decode")
+                else:
+                    self._complete_decode(ev["rid"], st, ev["tokens"])
+            driver.decode_active = int(rep.get("active", 0))
+            driver.decode_free = int(rep.get("free_slots",
+                                             driver.decode_free))
         self._shed_mid_decode(driver, handle, now)
         return foreign
 
@@ -1044,33 +1047,35 @@ class GraftServer:
         """Admit one queued decode request into the pool's running batch
         (read lock held). The admit reply carries the FIRST generated
         token, so TTFT stamps here."""
-        st = self._inflight.get(item.rid)
-        if st is None:
-            return
-        now = self.now_ms()
-        disagg = self._pool_role(driver.key) == "decode"
-        est_first = driver.est_cost_ms()
-        if disagg and self._handoff_ewma_ms is not None:
-            # the cross-pool KV handoff is real work on the TTFT path —
-            # charge it to the shed-slack model like a steal hop
-            est_first += self._handoff_ewma_ms
-        if self.shed_policy is not None and not st.shed_exempt:
-            blown = ShedPolicy.hopeless_decode(
-                now, st.ttft_deadline_ms, est_first,
-                st.deadline_ms, driver.tpot_est_ms(), st.max_new)
-            if blown:
-                if self.shed_policy.should_shed(item.client,
-                                                charge=st.max_new):
-                    self._shed(item.rid, st, "decode")
-                    return
-                st.shed_exempt = True
-        if item.trace:
-            q_ms = now - item.enqueued_ms
-            self._m_queue_ms.record(q_ms)
-            self.telemetry.span("queue", "server", q_ms, rid=item.rid,
-                                tid="pool/{}/{}-{}".format(*driver.key),
-                                args={"decode": True})
-        sig = self._decode_sig(st)
+        with phase("server/decode_admit"):
+            st = self._inflight.get(item.rid)
+            if st is None:
+                return
+            now = self.now_ms()
+            disagg = self._pool_role(driver.key) == "decode"
+            est_first = driver.est_cost_ms()
+            if disagg and self._handoff_ewma_ms is not None:
+                # the cross-pool KV handoff is real work on the TTFT
+                # path — charge it to the shed-slack model like a steal
+                # hop
+                est_first += self._handoff_ewma_ms
+            if self.shed_policy is not None and not st.shed_exempt:
+                blown = ShedPolicy.hopeless_decode(
+                    now, st.ttft_deadline_ms, est_first,
+                    st.deadline_ms, driver.tpot_est_ms(), st.max_new)
+                if blown:
+                    if self.shed_policy.should_shed(item.client,
+                                                    charge=st.max_new):
+                        self._shed(item.rid, st, "decode")
+                        return
+                    st.shed_exempt = True
+            if item.trace:
+                q_ms = now - item.enqueued_ms
+                self._m_queue_ms.record(q_ms)
+                self.telemetry.span("queue", "server", q_ms, rid=item.rid,
+                                    tid="pool/{}/{}-{}".format(*driver.key),
+                                    args={"decode": True})
+            sig = self._decode_sig(st)
         handoff = None
         if disagg:
             # two-phase admit: prompt prefill on a prefill-capable pool,
@@ -1106,33 +1111,35 @@ class GraftServer:
                     or st.decode_retries >= 2:
                 self._decode_local(item.rid, st, item.payload)
             else:
-                st.decode_retries += 1
-                driver.batcher.put(item)
+                with phase("server/decode_admit"):
+                    st.decode_retries += 1
+                    driver.batcher.put(item)
             return
-        driver.note_exec(admit_ms)       # prefill cost feeds est_cost_ms
-        if handoff is not None:
-            # the block transfer is the admit hop's extra freight: admit
-            # wall time IS the measured handoff cost
-            self.stats["kv_handoffs"] += 1
-            self._handoff_samples.append(admit_ms)
-            self._m_handoff_ms.record(admit_ms)
-            e = self._handoff_ewma_ms
-            self._handoff_ewma_ms = admit_ms if e is None \
-                else 0.8 * e + 0.2 * admit_ms
-        from repro.serving.kvcache import prefix_digest
-        self._note_affinity(prefix_digest(sig, item.payload,
-                                          self._kv_block_tokens()))
-        if st.t_first_ms <= 0.0:
-            # disagg stamped TTFT at the prefill reply already — the
-            # first token existed before the decode pool heard of us
-            st.t_first_ms = self.now_ms()
-        st.n_gen = 1
-        if r.get("done"):
-            self._complete_decode(item.rid, st, r["tokens"])
-            return
-        driver.decode_active += 1
-        driver.decode_free = max(driver.decode_free - 1, 0)
-        driver.decode_resident[item.rid] = item.client
+        with phase("server/decode_admit"):
+            driver.note_exec(admit_ms)   # prefill cost feeds est_cost_ms
+            if handoff is not None:
+                # the block transfer is the admit hop's extra freight:
+                # admit wall time IS the measured handoff cost
+                self.stats["kv_handoffs"] += 1
+                self._handoff_samples.append(admit_ms)
+                self._m_handoff_ms.record(admit_ms)
+                e = self._handoff_ewma_ms
+                self._handoff_ewma_ms = admit_ms if e is None \
+                    else 0.8 * e + 0.2 * admit_ms
+            from repro.serving.kvcache import prefix_digest
+            self._note_affinity(prefix_digest(sig, item.payload,
+                                              self._kv_block_tokens()))
+            if st.t_first_ms <= 0.0:
+                # disagg stamped TTFT at the prefill reply already — the
+                # first token existed before the decode pool heard of us
+                st.t_first_ms = self.now_ms()
+            st.n_gen = 1
+            if r.get("done"):
+                self._complete_decode(item.rid, st, r["tokens"])
+                return
+            driver.decode_active += 1
+            driver.decode_free = max(driver.decode_free - 1, 0)
+            driver.decode_resident[item.rid] = item.client
 
     def _prefill_handoff(self, driver: PoolDriver, item: BatchItem,
                          st: _InFlight, sig: tuple):
@@ -1205,32 +1212,38 @@ class GraftServer:
         win. Charge = tokens NOT delivered."""
         if self.shed_policy is None or not driver.decode_resident:
             return
-        tpot = driver.tpot_est_ms()
-        for rid in list(driver.decode_resident):
-            st = self._inflight.get(rid)
-            if st is None or st.shed_exempt:
-                continue
-            left = st.max_new - st.n_gen
-            if left <= 0:
-                continue
-            # rolling per-token deadline: the NEXT token must land within
-            # one TPOT budget, the LAST within the absolute deadline
-            if not ShedPolicy.hopeless_decode(
-                    now, now + st.tpot_ms, tpot, st.deadline_ms,
-                    tpot, left):
-                continue
-            if not self.shed_policy.should_shed(st.req.client,
-                                                charge=left):
-                st.shed_exempt = True
-                continue
+        doomed = []
+        with phase("server/decode_tick"):
+            tpot = driver.tpot_est_ms()
+            for rid in list(driver.decode_resident):
+                st = self._inflight.get(rid)
+                if st is None or st.shed_exempt:
+                    continue
+                left = st.max_new - st.n_gen
+                if left <= 0:
+                    continue
+                # rolling per-token deadline: the NEXT token must land
+                # within one TPOT budget, the LAST within the absolute
+                # deadline
+                if not ShedPolicy.hopeless_decode(
+                        now, now + st.tpot_ms, tpot, st.deadline_ms,
+                        tpot, left):
+                    continue
+                if not self.shed_policy.should_shed(st.req.client,
+                                                    charge=left):
+                    st.shed_exempt = True
+                    continue
+                doomed.append((rid, st))
+        for rid, st in doomed:
             try:
                 handle.decode_abort(rid)
             except Exception:
                 traceback.print_exc()
-            driver.decode_resident.pop(rid, None)
-            driver.decode_active = max(driver.decode_active - 1, 0)
-            driver.decode_free += 1
-            self._shed(rid, st, "decode")
+            with phase("server/decode_tick"):
+                driver.decode_resident.pop(rid, None)
+                driver.decode_active = max(driver.decode_active - 1, 0)
+                driver.decode_free += 1
+                self._shed(rid, st, "decode")
 
     def _complete_decode(self, rid: int, st: _InFlight, tokens) -> None:
         toks = [int(t) for t in tokens]

@@ -17,15 +17,39 @@ has to satisfy three constraints at once:
     per-thread cells — no lock is taken on the increment path, only on
     first touch by a new thread. Disabled telemetry is the shared
     :data:`NULL` registry whose instruments are no-op singletons, so an
-    un-instrumented run pays one dead method call per site. Spans are
-    *sampled* per request id (deterministic hash, so every hop of one
-    request agrees on the decision without coordination).
+    un-instrumented run pays one dead method call per site.
 
   * **Cross-process timelines.** Span timestamps are epoch
     milliseconds (``time.time``), the only clock subprocesses share, so
     a span opened on a front-end and closed on a worker hop lands on
     one Perfetto timeline. Export is Chrome trace-event JSON
     (``ph: "X"`` complete events + ``M`` name metadata) or JSONL.
+
+Two views of where time goes, each with its own clock:
+
+  * **Request spans** (:meth:`Telemetry.span`): one completed span per
+    hop of a request, keyed by its rid, stored in the registry and
+    exported by ``--trace-out``. A registry built with ``trace=True``
+    spans every request; the rid rides the wire so a worker closes its
+    hop's span on the right request.
+
+  * **Phases** (:func:`phase`): host spans written into the JAX
+    profiler's own trace, on the clock of the device's events, whenever
+    a profile is being collected — whatever the registry, :data:`NULL`
+    included, because the profiler is process-wide. They never enter the
+    span store. With no profile collecting a phase costs one check.
+    Names are stable ``<layer>/<what>`` strings (``decode/readback``,
+    ``kv/append``, ``transport/pack``, ``server/decode_tick``) that
+    trace readers match exactly. One rule: **a phase covers its own
+    layer's work and is closed before that layer calls into another
+    one.** Phases on one thread then never nest across layers, each
+    phase's duration is its layer's self time, and an idle stretch of
+    the device is named by the leaf that did the work, not by an
+    enclosing tick.
+
+    Capture a profile of a serving process by running it inside
+    ``jax.profiler.trace``; the README's Observability section gives
+    the command.
 
 The replan audit rides here too: :class:`ServingController` appends one
 :func:`audit_entry` per replan (trigger names, the window stats that
@@ -40,10 +64,11 @@ import threading
 import time
 from collections import deque
 from typing import Optional
-from zlib import crc32
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "Telemetry", "NULL",
+    "Counter", "Gauge", "Histogram", "Telemetry", "NULL", "phase",
     "GROWTH", "ZERO_IDX", "bucket_index", "bucket_value",
 ]
 
@@ -68,6 +93,15 @@ def bucket_value(idx: int) -> float:
     if idx == ZERO_IDX:
         return 0.0
     return GROWTH ** (idx + 0.5)
+
+
+def phase(name: str, **args) -> TraceAnnotation:
+    """Context manager for one phase: a host span named ``name``
+    (``<layer>/<what>``) in the JAX profiler's trace, with ``args`` as
+    its metadata (``bytes=...``). Recorded whenever a profile is being
+    collected, whatever the registry; nothing is written to any
+    registry. Close it before the layer calls into another layer."""
+    return TraceAnnotation(name, **args)
 
 
 class Counter:
@@ -283,10 +317,9 @@ class Telemetry:
     enabled = True
 
     def __init__(self, *, process: str = "main", trace: bool = False,
-                 trace_sample: float = 1.0, max_spans: int = 65_536):
+                 max_spans: int = 65_536):
         self.process = process
         self._trace = bool(trace)
-        self._sample = float(trace_sample)
         self._lock = threading.Lock()
         self._counters: dict = {}
         self._gauges: dict = {}
@@ -318,14 +351,9 @@ class Telemetry:
 
     # -------------------------------------------------------------- spans
     def want_trace(self, rid) -> bool:
-        """Deterministic per-request sampling decision: every hop (any
-        thread, any process) hashes the rid to the same verdict, so a
-        sampled request is traced end to end without coordination."""
-        if not self._trace:
-            return False
-        if self._sample >= 1.0:
-            return True
-        return (crc32(str(rid).encode()) & 0xFFFF) / 65536.0 < self._sample
+        """Whether request ``rid`` gets request spans: every request
+        when the registry was built with ``trace=True``."""
+        return self._trace
 
     def span(self, name: str, cat: str, dur_ms: float, *,
              t0_ms: Optional[float] = None, rid=None,

@@ -50,6 +50,8 @@ import numpy as np
 import ml_dtypes  # noqa: F401  registers bfloat16 & co. with numpy by name
 import msgpack
 
+from repro.serving.telemetry import phase
+
 __all__ = [
     "FrameError", "TruncatedFrameError", "TransferStats", "error_reply",
     "encode_frame", "decode_frame", "read_frame", "write_frame",
@@ -204,7 +206,9 @@ def kv_frame_nbytes(frame: dict) -> int:
 def encode_frame(msg: dict, *, max_frame_bytes: int = DEFAULT_MAX_FRAME
                  ) -> bytes:
     """``msg`` (msgpack-able dict, ndarrays allowed) -> framed bytes."""
-    body = msgpack.packb(msg, default=_pack_default, use_bin_type=True)
+    with phase("transport/pack") as ph:
+        body = msgpack.packb(msg, default=_pack_default, use_bin_type=True)
+        ph.set_metadata(bytes=len(body))
     if len(body) > max_frame_bytes:
         raise FrameError(f"frame of {len(body)} bytes exceeds "
                          f"max_frame_bytes={max_frame_bytes}")
@@ -254,8 +258,9 @@ def read_frame(readable, *, max_frame_bytes: int = DEFAULT_MAX_FRAME
                          f"max_frame_bytes={max_frame_bytes}")
     body = _read_exact(readable, length)
     try:
-        return msgpack.unpackb(body, object_hook=_unpack_hook, raw=False,
-                               strict_map_key=False)
+        with phase("transport/unpack", bytes=length):
+            return msgpack.unpackb(body, object_hook=_unpack_hook,
+                                   raw=False, strict_map_key=False)
     except FrameError:
         raise
     except Exception as e:
@@ -285,9 +290,7 @@ MAX_STAT_SAMPLES = 65_536      # per channel; long-running servers must
 @dataclass
 class TransferStats:
     """Per-channel transfer log: what actually crossed the hop. Bounded:
-    the oldest samples roll off past MAX_STAT_SAMPLES — consumers that
-    want every sample (the controller's bandwidth estimator) should
-    ``drain()`` periodically."""
+    the oldest samples roll off past MAX_STAT_SAMPLES."""
     samples: deque = field(
         default_factory=lambda: deque(maxlen=MAX_STAT_SAMPLES))
 
@@ -301,21 +304,6 @@ class TransferStats:
     @property
     def total_bytes(self) -> int:
         return sum(n for _, n, _ in self.samples)
-
-    @property
-    def total_ms(self) -> float:
-        return sum(ms for _, _, ms in self.samples)
-
-    def mean_bw(self) -> float:
-        """Mean measured throughput in bytes/s over all transfers."""
-        ms = self.total_ms
-        return self.total_bytes / (ms / 1e3) if ms > 0 else 0.0
-
-    def drain(self) -> list:
-        """Return and clear the sample log (consumers pull incrementally)."""
-        out = list(self.samples)
-        self.samples.clear()
-        return out
 
 
 # ---------------------------------------------------------------------------

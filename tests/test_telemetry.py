@@ -1,6 +1,7 @@
 """Telemetry: mergeable registry invariants, lock-free instrument
-thread-safety, and span propagation — across a socket-transport pool hop
-and across a mid-traffic replan (with the audit log it must leave)."""
+thread-safety, span propagation — across a socket-transport pool hop
+and across a mid-traffic replan (with the audit log it must leave) —
+and the phases the decode path writes into the profiler's trace."""
 import json
 import math
 import threading
@@ -9,7 +10,7 @@ import numpy as np
 import pytest
 
 from repro.serving.telemetry import (GROWTH, Histogram, NULL, Telemetry,
-                                     bucket_index)
+                                     bucket_index, phase)
 
 try:                     # minimal envs: property tests skip, the rest run
     from hypothesis import given, settings, strategies as st
@@ -290,3 +291,119 @@ def test_spans_and_audit_across_mid_traffic_replan(tmp_path):
         rep["served"]
     assert dump["histograms"]["replan/apply_ms"]["count"] >= len(stamped)
     assert len(dump["audit"]) == len(audit)
+
+
+# ------------------------------------------- phases in the profiler trace
+
+LAYERS = ("server", "transport", "decode", "kv")
+
+
+def _host_phases(log_dir) -> list:
+    """[[(name, start_ns, end_ns), ...] per host thread] of the phases in
+    the profile under ``log_dir``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path = max(glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            evs = [(ev.name, int(ev.start_ns),
+                    int(ev.start_ns + ev.duration_ns))
+                   for ev in line.events
+                   if ev.name.split("/", 1)[0] in LAYERS]
+            if evs:
+                out.append(evs)
+    return out
+
+
+@pytest.fixture(scope="module")
+def decode_profile(tmp_path_factory):
+    """A tiny decode pool behind a server, with a live registry, serving
+    two streams inside a profile (after one warm stream outside it):
+    (phases per thread, pool stats, registry)."""
+    import jax
+
+    from repro.serving.executor import GraftExecutor, ServeRequest
+    from repro.serving.server import GraftServer
+    from repro.serving.smoke import (decode_plan, smoke_fragments,
+                                     smoke_setup)
+    from repro.serving.transport import InProcessTransport
+
+    cfg, book, params = smoke_setup(seq_len=8, seed=0)
+    frags = smoke_fragments(cfg, 1, seed=0)
+    tel = Telemetry(process="t")
+    ex = GraftExecutor(decode_plan(cfg, book, frags, batch=2), params, cfg,
+                       transport=InProcessTransport(), decode_ctx=32,
+                       kv_blocks=32, kv_block_tokens=4, telemetry=tel)
+    server = GraftServer(ex, book=book).start()
+    rng = np.random.RandomState(5)
+
+    def serve(n):
+        for _ in range(n):
+            server.submit(ServeRequest(
+                client=frags[0].client,
+                tokens=rng.randint(0, cfg.vocab_size, 8).astype(np.int32),
+                max_new_tokens=4, tpot_budget_ms=1e5), 0, 1e5)
+        assert server.join(timeout=120.0)
+
+    log_dir = tmp_path_factory.mktemp("profile")
+    try:
+        serve(1)                                  # compiles outside
+        jax.profiler.start_trace(str(log_dir))
+        try:
+            serve(2)
+        finally:
+            jax.profiler.stop_trace()
+        stats = next(iter(ex.pool_stats().values()))
+    finally:
+        server.stop(drain=False, timeout=10.0)
+        ex.close()
+    return _host_phases(log_dir), stats, tel
+
+
+def test_decode_path_writes_its_phases(decode_profile):
+    threads, _, _ = decode_profile
+    names = [n for evs in threads for n, _, _ in evs]
+    assert {"decode/dispatch", "decode/sync", "decode/readback",
+            "decode/widen", "decode/prefill", "decode/row_copy",
+            "kv/append", "kv/write_prompt", "transport/pack",
+            "transport/unpack", "server/decode_tick",
+            "server/decode_admit"} <= set(names)
+    # each step reads K and V back, each copy widened once
+    steps = names.count("decode/dispatch")
+    assert steps >= 3
+    assert names.count("decode/readback") == 2 * steps
+    assert names.count("decode/widen") == 2 * steps
+
+
+def test_phases_never_enclose_another_layers(decode_profile):
+    threads, _, _ = decode_profile
+    for evs in threads:
+        for a, a0, a1 in evs:
+            for b, b0, b1 in evs:
+                if (a0, a1) != (b0, b1) and a0 <= b0 and b1 <= a1:
+                    assert a.split("/")[0] == b.split("/")[0], \
+                        f"{a} encloses {b}"
+
+
+def test_d2h_bytes_counted_per_step(decode_profile):
+    _, stats, tel = decode_profile
+    # a step reads the same arrays back whatever it serves
+    assert stats["d2h_bytes"] > 0
+    assert stats["d2h_bytes"] % stats["decode_steps"] == 0
+    assert tel.metrics_dump()["counters"]["pool/d2h_bytes"] == \
+        stats["d2h_bytes"]
+
+
+def test_phase_without_profiler_records_nothing():
+    live = Telemetry(process="t", trace=True)
+    with phase("decode/readback", bytes=8):
+        pass
+    assert not NULL.spans and not live.spans
+    dump = NULL.metrics_dump()
+    assert not dump["counters"] and not dump["histograms"]
