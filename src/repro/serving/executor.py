@@ -103,6 +103,17 @@ def _sig_tuple(x):
     return x
 
 
+def _with_written_rows(out: tuple, pos) -> tuple:
+    """A decode step's ``(logits, cache)`` and the K and V rows it wrote:
+    ``cache[k][l, b, pos[b]]``, (L, B, KV, hd) each. Every row is
+    gathered, so the shape is fixed; the index clamps, as an empty
+    slot's position keeps advancing (its row is ignored)."""
+    logits, cache = out
+    B, Sc = cache["k"].shape[1], cache["k"].shape[2]
+    rows, at = jnp.arange(B), jnp.clip(pos, 0, Sc - 1)
+    return logits, cache, cache["k"][:, rows, at], cache["v"][:, rows, at]
+
+
 class FragmentInstance:
     """One stage pool: jitted fragment program + a batching queue.
 
@@ -161,11 +172,13 @@ class FragmentInstance:
         self.kv: Optional[PagedKVCache] = None
         self._dc: Optional[dict] = None       # dense batched decode cache
         self._dstep = None                    # jitted batched decode_step
+        self._prefill = None                  # jitted B=1 prompt prefill
         self._slots: list = []                # per-row sequence state
         self.decode_admits = 0
         self.decode_steps = 0
         self.decode_tokens = 0                # admission firsts + step emits
-        self.d2h_bytes = 0                    # decode steps' device reads
+        self.d2h_bytes = 0                    # decode steps' reads: pos,
+                                              # tokens, the K/V rows written
         self.prefill_exports = 0              # cross-pool KV handoffs out
         self.kv_handoffs_in = 0               # cross-pool KV handoffs in
         # cross-request prefix sharing reconstructs a prompt's KV from the
@@ -331,8 +344,16 @@ class FragmentInstance:
         self._slots = [None] * B
         cfg = self.cfg
         self._dstep = jax.jit(
-            lambda params, cache, toks: decode_step(params, cfg, cache,
-                                                    toks))
+            lambda params, cache, toks: _with_written_rows(
+                decode_step(params, cfg, cache, toks), cache["pos"]))
+        ctx = self.decode_ctx
+
+        # one program per prompt length, reused by every admission of that
+        # length: called eagerly, prefill traces its layer scan anew and
+        # loads it from the compile cache on each admission
+        def _prefill(params, toks):
+            return prefill(params, cfg, toks, cache_seq=ctx)
+        self._prefill = jax.jit(_prefill)
 
     @staticmethod
     def _row_axis(key: str) -> int:
@@ -362,9 +383,8 @@ class FragmentInstance:
             k, v = self.kv.gather(rid, pop)   # (pop, L, KV, hd)
         with phase("decode/prefill"):
             if pop == 0:
-                logits, c1 = prefill(self._params, cfg,
-                                     jnp.asarray(toks)[None],
-                                     cache_seq=self.decode_ctx)
+                logits, c1 = self._prefill(self._params,
+                                           jnp.asarray(toks)[None])
             else:
                 c1 = init_cache(cfg, 1, self.decode_ctx)
                 kk = jnp.asarray(k).transpose(1, 0, 2, 3)[:, None]
@@ -378,16 +398,24 @@ class FragmentInstance:
                 c1["pos"] = jnp.full((1,), pop, jnp.int32)
                 logits = None
                 for t in toks[pop:]:
-                    logits, c1 = self._dstep(
+                    logits, c1, _, _ = self._dstep(
                         self._params, c1,
                         jnp.asarray([[int(t)]], jnp.int32))
             first = int(jnp.argmax(logits[0, -1]))
-            sl = np.arange(n_shared, S)       # arena-bound suffix positions
-            k_np = np.asarray(c1["k"], np.float32)
-            v_np = np.asarray(c1["v"], np.float32)
-            ks = k_np[:, 0, sl].transpose(1, 0, 2, 3)
-            vs = v_np[:, 0, sl].transpose(1, 0, 2, 3)
+            ks = self._suffix_rows(c1["k"], n_shared, S)
+            vs = self._suffix_rows(c1["v"], n_shared, S)
         return first, c1, ks, vs
+
+    @staticmethod
+    def _suffix_rows(x, lo: int, hi: int) -> np.ndarray:
+        """Positions ``lo..hi`` of row 0 of a B=1 cache array (L, 1, Sc,
+        KV, hd), sliced on the device, copied at its own dtype and widened
+        to the arena's float32: (hi - lo, L, KV, hd). The slice starts at
+        a traced index, so one program serves each length."""
+        start = (0, 0, lo) + (0,) * (x.ndim - 3)
+        size = (x.shape[0], 1, hi - lo) + x.shape[3:]
+        h = np.asarray(jax.lax.dynamic_slice(x, start, size))
+        return h[:, 0].astype(np.float32).transpose(1, 0, 2, 3)
 
     def prefill_export(self, rid: int, client: str, tokens,
                        sig: tuple) -> dict:
@@ -501,22 +529,21 @@ class FragmentInstance:
             for i in active:
                 toks[i, 0] = self._slots[i]["last"]
             pos_before = np.asarray(self._dc["pos"])
-            logits, self._dc = self._call_counted(
+            logits, self._dc, k_dev, v_dev = self._call_counted(
                 self._dstep, self._params, self._dc, jnp.asarray(toks))
         with phase("decode/sync"):
             nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-        k_np = self._read_back(self._dc["k"])
-        v_np = self._read_back(self._dc["v"])
-        n_read = (pos_before.nbytes + nxt.nbytes + self._dc["k"].nbytes
-                  + self._dc["v"].nbytes)
+        k_new = self._read_back(k_dev)        # (L, B, KV, hd) at pos_before
+        v_new = self._read_back(v_dev)
+        n_read = (pos_before.nbytes + nxt.nbytes + k_dev.nbytes
+                  + v_dev.nbytes)
         events = []
         for i in active:
             s = self._slots[i]
-            p = int(pos_before[i])            # slot == position (can_decode)
             ev = {"rid": s["rid"], "client": s["client"]}
             try:
                 self.kv.append(s["rid"], int(toks[i, 0]),
-                               k_np[:, i, p], v_np[:, i, p])
+                               k_new[:, i], v_new[:, i])
             except KVCacheOOM:
                 # admission reserved nothing: under pressure a boundary
                 # alloc can fail mid-stream — surface it as a forced
@@ -548,8 +575,9 @@ class FragmentInstance:
 
     @staticmethod
     def _read_back(x) -> np.ndarray:
-        """Device -> host copy of a decode-cache array at its own dtype,
-        then its widening to the arena's float32."""
+        """Device -> host copy of the K or V rows a step wrote, one
+        position per batch row (L, B, KV, hd), at the cache's own dtype,
+        then their widening to the arena's float32."""
         with phase("decode/readback", bytes=x.nbytes):
             h = np.asarray(x)
         with phase("decode/widen"):

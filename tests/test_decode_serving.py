@@ -276,6 +276,89 @@ def test_mid_decode_admission_preserves_numerics(decode_pool):
     assert done[102] == refB
 
 
+def _solo_pool(decode_pool, decode_ctx=32):
+    """A fresh decode instance of the fixture's one pool, driven
+    directly, so its dense cache and arena can be read."""
+    from repro.serving.executor import FragmentInstance
+    cfg, params, ex = decode_pool
+    spec = next(iter(ex.pool_specs().values()))
+    return FragmentInstance(params, cfg, spec, decode_ctx=decode_ctx,
+                            kv_blocks=32, kv_block_tokens=4)
+
+
+def _assert_arena_matches_dense(inst, rid):
+    """The arena's K and V of stream ``rid`` are its dense cache row at
+    the same positions, widened to float32, bit for bit."""
+    slot = next(i for i, s in enumerate(inst._slots) if s and s["rid"] == rid)
+    ks, vs = inst.kv.gather(rid)
+    n = ks.shape[0]
+    for got, dense in ((ks, inst._dc["k"]), (vs, inst._dc["v"])):
+        want = np.asarray(dense[:, slot, :n], np.float32).transpose(1, 0, 2, 3)
+        assert got.dtype == np.float32
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      want.view(np.uint32))
+
+
+def test_arena_holds_dense_cache_rows_exactly(decode_pool):
+    """Two streams, the second admitted mid-way through the first's
+    decode: after several steps the arena holds exactly what the dense
+    cache holds for each."""
+    cfg = decode_pool[0]
+    inst = _solo_pool(decode_pool)
+    rng = np.random.RandomState(7)
+    tA = rng.randint(0, cfg.vocab_size, 8).astype(np.int32)
+    tB = rng.randint(0, cfg.vocab_size, 6).astype(np.int32)
+    assert inst.decode_admit(401, "c0", tA, 12, sig=("e", 0, 0))["admitted"]
+    for _ in range(3):
+        inst.decode_step_batch()
+    assert inst.decode_admit(402, "c1", tB, 12, sig=("e", 0, 0))["admitted"]
+    for _ in range(4):
+        inst.decode_step_batch()
+    assert inst.kv.gather(401)[0].shape[0] == 8 + 7
+    _assert_arena_matches_dense(inst, 401)
+    _assert_arena_matches_dense(inst, 402)
+
+
+def test_arena_exact_after_shared_prefix_admission(decode_pool):
+    """A prompt whose first blocks are already in the arena steps its
+    suffix through the B=1 step loop; the arena still holds exactly the
+    dense cache's row."""
+    cfg = decode_pool[0]
+    inst = _solo_pool(decode_pool)
+    rng = np.random.RandomState(8)
+    head = rng.randint(0, cfg.vocab_size, 8).astype(np.int32)
+    tail = rng.randint(0, cfg.vocab_size, 3).astype(np.int32)
+    r0 = inst.decode_admit(501, "c0", head, 1, sig=("f", 0, 0))
+    assert r0["admitted"] and r0["done"]          # retained in the arena
+    r1 = inst.decode_admit(502, "c0", np.concatenate([head, tail]), 6,
+                           sig=("f", 0, 0))
+    assert r1["admitted"] and r1["n_shared"] == 8
+    for _ in range(3):
+        inst.decode_step_batch()
+    _assert_arena_matches_dense(inst, 502)
+
+
+def test_step_reads_written_rows_not_the_cache(decode_pool):
+    """A step reads back pos, its tokens and the K and V rows it wrote,
+    one position per slot, whatever the cache's length."""
+    cfg = decode_pool[0]
+    rng = np.random.RandomState(9)
+    toks = rng.randint(0, cfg.vocab_size, 8).astype(np.int32)
+    per_step = []
+    for ctx in (32, 64):
+        inst = _solo_pool(decode_pool, decode_ctx=ctx)
+        assert inst.decode_admit(601, "c0", toks, 4, sig=("g", 0, 0))[
+            "admitted"]
+        for _ in range(2):
+            inst.decode_step_batch()
+        B = len(inst._slots)
+        item = inst._dc["k"].dtype.itemsize
+        rows = cfg.n_layers * B * cfg.n_kv_heads * cfg.head_dim_ * item
+        assert inst.d2h_bytes == 2 * (B * 4 + B * 4 + 2 * rows)
+        per_step.append(inst.d2h_bytes // inst.decode_steps)
+    assert per_step[0] == per_step[1]
+
+
 def test_decode_abort_frees_slot_and_blocks(decode_pool):
     cfg, params, ex = decode_pool
     key = next(iter(ex.pool_specs()))
