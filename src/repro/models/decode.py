@@ -104,23 +104,23 @@ def _block_decode(p: dict, cfg: ModelConfig, x: Array, c: dict,
     """One-token decode through one block. c holds this layer's cache slice."""
     new_c = dict(c)
     if cfg.family == "ssm":
-        h = nn.apply_norm(p["ln1"], cfg, x)
+        h = nn.apply_norm(nn.norm_params(p, "ln1"), cfg, x)
         y, new_c["shift_tm"], new_c["wkv"] = rwkv_mod.time_mix_decode(
             p["time_mix"], cfg, h, c["shift_tm"], c["wkv"])
         x = x + y
-        h = nn.apply_norm(p["ln2"], cfg, x)
+        h = nn.apply_norm(nn.norm_params(p, "ln2"), cfg, x)
         y, new_c["shift_cm"] = rwkv_mod.channel_mix(
             p["channel_mix"], cfg, h, shift_carry=c["shift_cm"])
         return x + y, new_c
     if kind == "cross":
-        h = nn.apply_norm(p["ln1"], cfg, x)
+        h = nn.apply_norm(nn.norm_params(p, "ln1"), cfg, x)
         y, _, _, _ = attn.attn_decode(p["xattn"], cfg, h, None, None, pos,
                                       kv_pos, cross_kv=memory_kv)
         x = x + jnp.tanh(p["gate_attn"]).astype(x.dtype) * y
-        h = nn.apply_norm(p["ln2"], cfg, x)
+        h = nn.apply_norm(nn.norm_params(p, "ln2"), cfg, x)
         return x + jnp.tanh(p["gate_mlp"]).astype(x.dtype) \
             * nn.apply_mlp(p["mlp"], cfg, h), new_c
-    h = nn.apply_norm(p["ln1"], cfg, x)
+    h = nn.apply_norm(nn.norm_params(p, "ln1"), cfg, x)
     scales = (c["k_scale"], c["v_scale"]) if "k_scale" in c else None
     y, new_c["k"], new_c["v"], new_scales = attn.attn_decode(
         p["attn"], cfg, h, c["k"], c["v"], pos, kv_pos,
@@ -133,11 +133,11 @@ def _block_decode(p: dict, cfg: ModelConfig, x: Array, c: dict,
         y = 0.5 * (y + ys)
     x = x + y
     if kind == "dec":
-        h = nn.apply_norm(p["lnx"], cfg, x)
+        h = nn.apply_norm(nn.norm_params(p, "lnx"), cfg, x)
         y, _, _, _ = attn.attn_decode(p["xattn"], cfg, h, None, None, pos,
                                       kv_pos, cross_kv=memory_kv)
         x = x + y
-    h = nn.apply_norm(p["ln2"], cfg, x)
+    h = nn.apply_norm(nn.norm_params(p, "ln2"), cfg, x)
     if cfg.family == "moe":
         y, _ = moe_mod.moe_forward(p["moe"], cfg, h)
     else:
@@ -251,10 +251,10 @@ def prefill(params: dict, cfg: ModelConfig, tokens: Array, *,
 
     if cfg.family == "ssm":
         def body(h, p_l):
-            hn = nn.apply_norm(p_l["ln1"], cfg, h)
+            hn = nn.apply_norm(nn.norm_params(p_l, "ln1"), cfg, h)
             y, sh_tm, wkv = rwkv_mod.time_mix_forward(p_l["time_mix"], cfg, hn)
             h = h + y
-            hn = nn.apply_norm(p_l["ln2"], cfg, h)
+            hn = nn.apply_norm(nn.norm_params(p_l, "ln2"), cfg, h)
             y, sh_cm = rwkv_mod.channel_mix(p_l["channel_mix"], cfg, hn)
             return h + y, {"wkv": wkv, "shift_tm": sh_tm, "shift_cm": sh_cm}
         x, lc = jax.lax.scan(body, x, params["blocks"])
@@ -287,11 +287,11 @@ def prefill(params: dict, cfg: ModelConfig, tokens: Array, *,
         img = extras["images"]
         def gbody(h, p_g):
             def sbody(hh, p_l):
-                hn = nn.apply_norm(p_l["ln1"], cfg, hh)
+                hn = nn.apply_norm(nn.norm_params(p_l, "ln1"), cfg, hh)
                 y, (k, v) = attn.attn_forward(p_l["attn"], cfg, hn,
                                               window=W, return_kv=True)
                 hh = hh + y
-                hn = nn.apply_norm(p_l["ln2"], cfg, hh)
+                hn = nn.apply_norm(nn.norm_params(p_l, "ln2"), cfg, hh)
                 hh = hh + nn.apply_mlp(p_l["mlp"], cfg, hn)
                 cap = capture(k, v)
                 return hh, (cap if not quant else {"kv": cap[0],
@@ -319,7 +319,7 @@ def prefill(params: dict, cfg: ModelConfig, tokens: Array, *,
             kind = "dec"
 
         def body(h, p_l):
-            hn = nn.apply_norm(p_l["ln1"], cfg, h)
+            hn = nn.apply_norm(nn.norm_params(p_l, "ln1"), cfg, h)
             y, (k, v) = attn.attn_forward(p_l["attn"], cfg, hn, window=W,
                                           return_kv=True)
             lc = {}
@@ -329,12 +329,12 @@ def prefill(params: dict, cfg: ModelConfig, tokens: Array, *,
                 y = 0.5 * (y + ys)
             h = h + y
             if kind == "dec":
-                hn = nn.apply_norm(p_l["lnx"], cfg, h)
+                hn = nn.apply_norm(nn.norm_params(p_l, "lnx"), cfg, h)
                 h = h + attn.attn_forward(p_l["xattn"], cfg, hn, kv_src=mem,
                                           causal=False)
                 xk, xv = attn.project_cross_kv(p_l["xattn"], cfg, mem)
                 lc["xk"], lc["xv"] = xk, xv
-            hn = nn.apply_norm(p_l["ln2"], cfg, h)
+            hn = nn.apply_norm(nn.norm_params(p_l, "ln2"), cfg, h)
             if cfg.family == "moe":
                 y, _ = moe_mod.moe_forward(p_l["moe"], cfg, hn)
             else:

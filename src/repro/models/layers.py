@@ -40,14 +40,22 @@ def embed_init(key, vocab: int, dim: int, dtype) -> Array:
 # Normalisation
 # ---------------------------------------------------------------------------
 
-def init_norm(cfg: ModelConfig, dim: Optional[int] = None) -> dict:
-    dim = dim or cfg.d_model
+def init_norm(cfg: ModelConfig, *names: str) -> dict:
+    """``{name: parameters}`` for each named norm. A non-parametric
+    LayerNorm (OLMo) has no parameters and holds no node in the tree, so
+    it yields no entry; readers take a norm through :func:`norm_params`."""
     if cfg.nonparametric_ln:
         return {}
-    p = {"scale": jnp.ones((dim,), jnp.float32)}
+    p = {"scale": jnp.ones((cfg.d_model,), jnp.float32)}
     if not cfg.rmsnorm:
-        p["bias"] = jnp.zeros((dim,), jnp.float32)
-    return p
+        p["bias"] = jnp.zeros((cfg.d_model,), jnp.float32)
+    return {n: dict(p) for n in names}
+
+
+def norm_params(p: dict, name: str) -> dict:
+    """The parameters of norm ``name`` in ``p``; ``{}`` for a norm that
+    has none."""
+    return p.get(name, {})
 
 
 def apply_norm(params: dict, cfg: ModelConfig, x: Array, eps: float = 1e-5) -> Array:

@@ -55,7 +55,7 @@ def init_block(key, cfg: ModelConfig, *, kind: str = "self") -> dict:
     """kind: self | cross (vlm gated cross block) | enc (bidirectional) |
     dec (whisper decoder: self + cross)."""
     ks = jax.random.split(key, 6)
-    p: dict = {"ln1": nn.init_norm(cfg), "ln2": nn.init_norm(cfg)}
+    p: dict = nn.init_norm(cfg, "ln1", "ln2")
     if cfg.family == "ssm":
         p["time_mix"] = rwkv_mod.init_time_mix(ks[0], cfg)
         p["channel_mix"] = rwkv_mod.init_channel_mix(ks[1], cfg)
@@ -69,7 +69,7 @@ def init_block(key, cfg: ModelConfig, *, kind: str = "self") -> dict:
     p["attn"] = attn.init_attention(ks[0], cfg)
     if kind == "dec":
         p["xattn"] = attn.init_attention(ks[1], cfg, cross=True)
-        p["lnx"] = nn.init_norm(cfg)
+        p.update(nn.init_norm(cfg, "lnx"))
     if cfg.family == "moe":
         p["moe"] = moe_mod.init_moe(ks[2], cfg)
     else:
@@ -89,7 +89,7 @@ def init_params(key, cfg: ModelConfig) -> dict:
     dt = jnp.dtype(cfg.dtype)
     p: dict = {
         "embed": nn.embed_init(ks[0], cfg.vocab_size, cfg.d_model, dt),
-        "final_norm": nn.init_norm(cfg),
+        **nn.init_norm(cfg, "final_norm"),
     }
     if not cfg.tie_embeddings:
         p["lm_head"] = nn.dense_init(ks[1], cfg.d_model, cfg.vocab_size, dt)
@@ -104,7 +104,7 @@ def init_params(key, cfg: ModelConfig) -> dict:
     elif cfg.family == "audio":
         p["enc_blocks"] = init_stack(ks[2], cfg, cfg.audio.n_encoder_layers,
                                      kind="enc")
-        p["enc_norm"] = nn.init_norm(cfg)
+        p.update(nn.init_norm(cfg, "enc_norm"))
         p["blocks"] = init_stack(ks[3], cfg, cfg.n_layers, kind="dec")
     else:
         p["blocks"] = init_stack(ks[2], cfg, cfg.n_layers, kind="self")
@@ -130,30 +130,32 @@ def block_forward(p: dict, cfg: ModelConfig, x: Array, *,
     aux = jnp.zeros((), jnp.float32)
     if cfg.family == "ssm":
         y, _, _ = rwkv_mod.time_mix_forward(
-            p["time_mix"], cfg, nn.apply_norm(p["ln1"], cfg, x))
+            p["time_mix"], cfg,
+            nn.apply_norm(nn.norm_params(p, "ln1"), cfg, x))
         x = x + y
         y, _ = rwkv_mod.channel_mix(
-            p["channel_mix"], cfg, nn.apply_norm(p["ln2"], cfg, x))
+            p["channel_mix"], cfg,
+            nn.apply_norm(nn.norm_params(p, "ln2"), cfg, x))
         return x + y, aux
     if kind == "cross":
-        h = nn.apply_norm(p["ln1"], cfg, x)
+        h = nn.apply_norm(nn.norm_params(p, "ln1"), cfg, x)
         y = attn.attn_forward(p["xattn"], cfg, h, kv_src=memory, causal=False)
         x = x + jnp.tanh(p["gate_attn"]).astype(x.dtype) * y
-        h = nn.apply_norm(p["ln2"], cfg, x)
+        h = nn.apply_norm(nn.norm_params(p, "ln2"), cfg, x)
         x = x + jnp.tanh(p["gate_mlp"]).astype(x.dtype) \
             * nn.apply_mlp(p["mlp"], cfg, h)
         return x, aux
-    h = nn.apply_norm(p["ln1"], cfg, x)
+    h = nn.apply_norm(nn.norm_params(p, "ln1"), cfg, x)
     y = attn.attn_forward(p["attn"], cfg, h, window=window, causal=causal,
                           positions=positions, seg_ids=seg_ids)
     if cfg.family == "hybrid":
         y = 0.5 * (y + ssm_mod.ssm_forward(p["ssm"], cfg, h))
     x = x + y
     if kind == "dec":
-        h = nn.apply_norm(p["lnx"], cfg, x)
+        h = nn.apply_norm(nn.norm_params(p, "lnx"), cfg, x)
         x = x + attn.attn_forward(p["xattn"], cfg, h, kv_src=memory,
                                   causal=False)
-    h = nn.apply_norm(p["ln2"], cfg, x)
+    h = nn.apply_norm(nn.norm_params(p, "ln2"), cfg, x)
     if cfg.family == "moe":
         y, aux = moe_mod.moe_forward(p["moe"], cfg, h)
     else:
@@ -209,7 +211,7 @@ def embed_tokens(params: dict, cfg: ModelConfig, tokens: Array) -> Array:
 
 
 def unembed(params: dict, cfg: ModelConfig, x: Array) -> Array:
-    x = nn.apply_norm(params["final_norm"], cfg, x)
+    x = nn.apply_norm(nn.norm_params(params, "final_norm"), cfg, x)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     return x @ head
 
@@ -221,7 +223,7 @@ def encode_audio(params: dict, cfg: ModelConfig, frames: Array) -> Array:
     h = frames + pos[None]
     h, _ = stack_forward(params["enc_blocks"], cfg, h, causal=False,
                          kind="enc")
-    return nn.apply_norm(params["enc_norm"], cfg, h)
+    return nn.apply_norm(nn.norm_params(params, "enc_norm"), cfg, h)
 
 
 def forward(params: dict, cfg: ModelConfig, tokens: Array, *,

@@ -1,5 +1,6 @@
-"""Compile the served Pallas kernels for a described TPU v5e at qwen3-1.7b
-widths (16 query heads, 8 KV heads, head_dim 128, bf16).
+"""Compile the served Pallas kernels for a described TPU v5e at the served
+attention layouts: qwen3-1.7b (16 query heads, 8 KV heads) and olmo-1b (16
+and 16, a group of one), head_dim 128, bf16.
 
 Nothing here runs: the TPU compiler refuses what the chip would refuse
 (illegal tiles, too much VMEM), which interpret-mode tests cannot show.
@@ -17,7 +18,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 
-H, KV, HD = 16, 8, 128
+HD = 128
+# (query heads, KV heads): qwen3-1.7b grouped, olmo-1b multi-head
+HEADS = pytest.mark.parametrize("H,KV", [(16, 8), (16, 16)])
 DT = jnp.bfloat16
 
 
@@ -57,7 +60,8 @@ def _assert_kernel(fn, *args):
     assert "tpu_custom_call" in text
 
 
-def test_decode_attention_batched(one_chip):
+@HEADS
+def test_decode_attention_batched(one_chip, H, KV):
     """Continuous batching steps the decode kernel at the pool's batch."""
     B, ctx = 4, 256
 
@@ -71,8 +75,9 @@ def test_decode_attention_batched(one_chip):
                    _spec((B, ctx), one_chip, jnp.int32))
 
 
+@HEADS
 @pytest.mark.parametrize("T", [48, 272, 512])
-def test_packed_flash_attention(one_chip, T):
+def test_packed_flash_attention(one_chip, T, H, KV):
     """Packed totals are multiples of 16; 272 has no 128-aligned divisor
     tile and must be padded."""
     def packed(q, k, v, seg):
@@ -85,8 +90,9 @@ def test_packed_flash_attention(one_chip, T):
                    _spec((1, T), one_chip, jnp.int32))
 
 
+@HEADS
 @pytest.mark.parametrize("S", [12, 300])
-def test_prefill_attention(one_chip, S):
+def test_prefill_attention(one_chip, S, H, KV):
     """The unpacked prefill of a decode admission and of the mobile part."""
     def prefill(q, k, v):
         return ops.attention(q, k, v, causal=True, impl="pallas")
@@ -100,6 +106,7 @@ def test_prefill_attention(one_chip, S):
 def test_attention_gradient(one_chip, S):
     """Training differentiates the same entry point: the logsumexp-saving
     forward and both backward kernels compile, padded length included."""
+    H, KV = 16, 8
     def loss(q, k, v):
         out = ops.attention(q, k, v, causal=True, impl="pallas")
         return jnp.sum(out.astype(jnp.float32))
