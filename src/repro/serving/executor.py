@@ -114,6 +114,17 @@ def _with_written_rows(out: tuple, pos) -> tuple:
     return logits, cache, cache["k"][:, rows, at], cache["v"][:, rows, at]
 
 
+@jax.jit
+def _seed_prefix(c1: dict, k, v, pop):
+    """B=1 cache ``c1`` holding the K and V gathered from the arena at
+    positions below ``pop`` (zero beyond), ready to step from ``pop``.
+    ``pop`` is traced: one program serves every prefix length."""
+    at = jnp.arange(k.shape[2], dtype=jnp.int32)
+    return {**c1, "k": k.astype(c1["k"].dtype), "v": v.astype(c1["v"].dtype),
+            "kv_pos": jnp.where(at < pop, at, -1)[None],
+            "pos": jnp.full((1,), pop, jnp.int32)}
+
+
 class FragmentInstance:
     """One stage pool: jitted fragment program + a batching queue.
 
@@ -177,8 +188,9 @@ class FragmentInstance:
         self.decode_admits = 0
         self.decode_steps = 0
         self.decode_tokens = 0                # admission firsts + step emits
-        self.d2h_bytes = 0                    # decode steps' reads: pos,
-                                              # tokens, the K/V rows written
+        self.d2h_bytes = 0                    # device->host: tokens of
+                                              # steps and admissions, and
+                                              # exported KV blocks
         self.prefill_exports = 0              # cross-pool KV handoffs out
         self.kv_handoffs_in = 0               # cross-pool KV handoffs in
         # cross-request prefix sharing reconstructs a prompt's KV from the
@@ -335,12 +347,14 @@ class FragmentInstance:
         if self._dc is not None:
             return
         B = max(self.batch, 1)
+        self._dc = init_cache(self.cfg, B, self.decode_ctx)
         self.kv = PagedKVCache(self.kv_blocks, self.kv_block_tokens,
                                n_layers=self.cfg.n_layers,
                                n_kv_heads=self.cfg.n_kv_heads,
                                head_dim=self.cfg.head_dim_,
+                               max_seq_tokens=self.decode_ctx,
+                               dtype=self._dc["k"].dtype,
                                telemetry=self.telemetry)
-        self._dc = init_cache(self.cfg, B, self.decode_ctx)
         self._slots = [None] * B
         cfg = self.cfg
         self._dstep = jax.jit(
@@ -372,50 +386,38 @@ class FragmentInstance:
         return out
 
     def _solo_prefill(self, rid: int, toks: np.ndarray, n_shared: int):
-        """B=1 prompt processing for one admission: gather the shared
-        prefix KV from the paged arena (keeping at least the LAST prompt
-        token to recompute, so a fully-shared prompt still yields first-
-        token logits), step the remainder, and return the first generated
-        token, the cache row, and the arena-bound suffix KV."""
+        """B=1 prompt processing for one admission: seed the cache row
+        with the shared prefix KV gathered from the paged arena on the
+        device (keeping at least the LAST prompt token to recompute, so a
+        fully-shared prompt still yields first-token logits), step the
+        remainder, write the prompt's private blocks into the arena from
+        that row, and return the first generated token and the row. Only
+        the token leaves the device."""
         cfg, S = self.cfg, int(toks.shape[0])
         pop = min(n_shared, S - 1)            # prefix positions gathered
         if pop:
-            k, v = self.kv.gather(rid, pop)   # (pop, L, KV, hd)
+            k, v = self.kv.gather(rid, pop)   # (L, 1, ctx, KV, hd)
         with phase("decode/prefill"):
             if pop == 0:
                 logits, c1 = self._prefill(self._params,
                                            jnp.asarray(toks)[None])
             else:
-                c1 = init_cache(cfg, 1, self.decode_ctx)
-                kk = jnp.asarray(k).transpose(1, 0, 2, 3)[:, None]
-                vv = jnp.asarray(v).transpose(1, 0, 2, 3)[:, None]
-                c1["k"] = c1["k"].at[:, :, :pop].set(
-                    kk.astype(c1["k"].dtype))
-                c1["v"] = c1["v"].at[:, :, :pop].set(
-                    vv.astype(c1["v"].dtype))
-                c1["kv_pos"] = c1["kv_pos"].at[0, :pop].set(
-                    jnp.arange(pop, dtype=jnp.int32))
-                c1["pos"] = jnp.full((1,), pop, jnp.int32)
+                c1 = _seed_prefix(init_cache(cfg, 1, self.decode_ctx), k, v,
+                                  np.int32(pop))
                 logits = None
                 for t in toks[pop:]:
                     logits, c1, _, _ = self._dstep(
                         self._params, c1,
                         jnp.asarray([[int(t)]], jnp.int32))
-            first = int(jnp.argmax(logits[0, -1]))
-            ks = self._suffix_rows(c1["k"], n_shared, S)
-            vs = self._suffix_rows(c1["v"], n_shared, S)
-        return first, c1, ks, vs
+            tok = jnp.argmax(logits[0, -1])
+            first = int(tok)
+        self._count_d2h(tok.nbytes)
+        self.kv.write_prompt_kv(rid, c1["k"], c1["v"])
+        return first, c1
 
-    @staticmethod
-    def _suffix_rows(x, lo: int, hi: int) -> np.ndarray:
-        """Positions ``lo..hi`` of row 0 of a B=1 cache array (L, 1, Sc,
-        KV, hd), sliced on the device, copied at its own dtype and widened
-        to the arena's float32: (hi - lo, L, KV, hd). The slice starts at
-        a traced index, so one program serves each length."""
-        start = (0, 0, lo) + (0,) * (x.ndim - 3)
-        size = (x.shape[0], 1, hi - lo) + x.shape[3:]
-        h = np.asarray(jax.lax.dynamic_slice(x, start, size))
-        return h[:, 0].astype(np.float32).transpose(1, 0, 2, 3)
+    def _count_d2h(self, nbytes: int) -> None:
+        self.d2h_bytes += nbytes
+        self._m_d2h_bytes.inc(nbytes)
 
     def prefill_export(self, rid: int, client: str, tokens,
                        sig: tuple) -> dict:
@@ -444,9 +446,9 @@ class FragmentInstance:
             n_shared = self.kv.begin(rid, key, toks)
         except KVCacheOOM:
             return {"exported": False, "reason": "kv_oom"}
-        first, _c1, ks, vs = self._solo_prefill(rid, toks, n_shared)
-        self.kv.write_prompt_kv(rid, ks, vs)
+        first, _c1 = self._solo_prefill(rid, toks, n_shared)
         payload = self.kv.export_prefix(rid)
+        self._count_d2h(payload["d2h_bytes"])
         self.kv.finish(rid, retain=self._kv_share)
         self.prefill_exports += 1
         self.decode_tokens += 1
@@ -495,8 +497,7 @@ class FragmentInstance:
             n_shared = self.kv.begin(rid, key, toks)
         except KVCacheOOM:
             return {"admitted": False, "reason": "kv_oom"}
-        first, c1, ks, vs = self._solo_prefill(rid, toks, n_shared)
-        self.kv.write_prompt_kv(rid, ks, vs)
+        first, c1 = self._solo_prefill(rid, toks, n_shared)
         done = max_new == 1
         if done:
             self.kv.finish(rid, retain=self._kv_share)
@@ -528,23 +529,23 @@ class FragmentInstance:
             toks = np.zeros((B, 1), np.int32)
             for i in active:
                 toks[i, 0] = self._slots[i]["last"]
-            pos_before = np.asarray(self._dc["pos"])
             logits, self._dc, k_dev, v_dev = self._call_counted(
                 self._dstep, self._params, self._dc, jnp.asarray(toks))
+            nxt = jnp.argmax(logits[:, -1], axis=-1)
+        # the rows the step wrote, (L, B, KV, hd), go to the arena on the
+        # device; the host's bookkeeping overlaps the step
+        oom = set(self.kv.append([s["rid"] if s else None
+                                  for s in self._slots],
+                                 toks[:, 0], k_dev, v_dev))
         with phase("decode/sync"):
-            nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-        k_new = self._read_back(k_dev)        # (L, B, KV, hd) at pos_before
-        v_new = self._read_back(v_dev)
-        n_read = (pos_before.nbytes + nxt.nbytes + k_dev.nbytes
-                  + v_dev.nbytes)
+            nxt.block_until_ready()
+        with phase("decode/readback", bytes=nxt.nbytes):
+            nxt = np.asarray(nxt)             # the step's only copy down
         events = []
         for i in active:
             s = self._slots[i]
             ev = {"rid": s["rid"], "client": s["client"]}
-            try:
-                self.kv.append(s["rid"], int(toks[i, 0]),
-                               k_new[:, i], v_new[:, i])
-            except KVCacheOOM:
+            if s["rid"] in oom:
                 # admission reserved nothing: under pressure a boundary
                 # alloc can fail mid-stream — surface it as a forced
                 # finish so the server sheds instead of wedging the batch
@@ -567,21 +568,10 @@ class FragmentInstance:
             events.append(ev)
         self.decode_steps += 1
         self.decode_tokens += len(active)
-        self.d2h_bytes += n_read
-        self._m_d2h_bytes.inc(n_read)
+        self._count_d2h(nxt.nbytes)
         return {"events": events,
                 "active": sum(1 for s in self._slots if s),
                 "free_slots": sum(1 for s in self._slots if s is None)}
-
-    @staticmethod
-    def _read_back(x) -> np.ndarray:
-        """Device -> host copy of the K or V rows a step wrote, one
-        position per batch row (L, B, KV, hd), at the cache's own dtype,
-        then their widening to the arena's float32."""
-        with phase("decode/readback", bytes=x.nbytes):
-            h = np.asarray(x)
-        with phase("decode/widen"):
-            return h.astype(np.float32, copy=False)
 
     def decode_abort(self, rid: int) -> bool:
         """Evict one resident sequence (mid-decode shed): free its KV

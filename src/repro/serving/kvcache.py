@@ -1,10 +1,10 @@
 """Paged KV-cache manager for autoregressive (decode) fragments.
 
 Each decode-capable stage pool owns ONE :class:`PagedKVCache`: a
-preallocated host-side arena of fixed-size token blocks that backs the
-KV state of every request resident in that pool's continuous decode
-batch. The design is the vLLM paged-attention bookkeeping reduced to
-what the serving path needs:
+preallocated arena of fixed-size token blocks, held on the device, that
+backs the KV state of every request resident in that pool's continuous
+decode batch. The design is the vLLM paged-attention bookkeeping reduced
+to what the serving path needs:
 
 - **Block-granular alloc/free.** A free list over ``n_blocks`` blocks of
   ``block_tokens`` token slots each; sequences hold chains of blocks and
@@ -24,17 +24,30 @@ what the serving path needs:
   before raising :class:`KVCacheOOM`. Eviction / hit / COW counters are
   surfaced in pool stats and gated in the decode bench.
 
-The arena stores float32 KV stacked over layers — ``(block,
-slot, layer, kv_head, head_dim)`` — because it is written from and
-gathered back into the pool's dense decode cache on the host side;
-dtype conversion happens at the gather/write boundary.
+Storage and bookkeeping are split. K and V are two device arrays stacked
+over layers — ``(block, slot, layer, kv_head, head_dim)`` — at the dtype
+of the pool's dense decode cache (float32 by default), so the arena
+holds the cache's own values and nothing widens or narrows them. Every
+data operation is one jitted program of fixed shape that donates the
+arena, so it updates in place: a prompt's whole private blocks written
+from the B=1 prefill cache, one row per stream appended from a decode
+step's written rows, a block copied for copy-on-write, and a sequence's
+blocks gathered back into the B=1 cache layout. Block tables are padded
+to ``max_seq_tokens`` with out-of-range entries, which the scatter drops
+and the gather fills with zeros, so no program is traced per prompt
+length. Block chains, refcounts, the prefix index, LRU and COW decisions
+stay on the host. KV reaches the host only in :meth:`export_prefix`, the
+prefill side of a disaggregated handoff.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 from dataclasses import dataclass, field
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.serving.telemetry import NULL as NULL_TELEMETRY, phase
@@ -63,6 +76,7 @@ class _Seq:
     n_tokens: int = 0                              # resident tokens (total)
     prompt_len: int = 0
     n_shared: int = 0                              # prefix tokens reused
+    n_shared_blocks: int = 0                       # ...in this many blocks
     prompt_keys: list = field(default_factory=list)  # chain keys per block
 
 
@@ -106,19 +120,78 @@ def prefix_digest(sig: tuple, tokens, block_tokens: int, *,
     return tuple(key_digest(k) for k in keys)
 
 
+# The arena's data operations. K and V are donated, so each updates in
+# place; the block table and positions are traced, so one program serves
+# every prompt length. Out-of-range block indices (``n_blocks``) pad the
+# tables: a scatter drops them, a gather reads zeros there.
+
+def _cache_blocks(x, nb: int, bt: int, dtype):
+    """Row 0 of a B=1 cache array (L, 1, S, KV, hd), S <= nb * bt, as
+    ``nb`` whole blocks in the arena's layout (nb, bt, L, KV, hd)."""
+    x = jnp.pad(x[:, 0], ((0, 0), (0, nb * bt - x.shape[2]), (0, 0), (0, 0)))
+    L, _, KV, hd = x.shape
+    return x.reshape(L, nb, bt, KV, hd).transpose(1, 2, 0, 3, 4).astype(dtype)
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _write_blocks(ak, av, k, v, table):
+    """Cache block j of ``k``, ``v`` (positions j*bt onwards) into arena
+    block ``table[j]``."""
+    nb, bt = table.shape[0], ak.shape[1]
+    return (ak.at[table].set(_cache_blocks(k, nb, bt, ak.dtype), mode="drop"),
+            av.at[table].set(_cache_blocks(v, nb, bt, av.dtype), mode="drop"))
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _append_rows(ak, av, k, v, blk, slot):
+    """Row b of ``k``, ``v`` (L, B, KV, hd) into slot ``slot[b]`` of arena
+    block ``blk[b]``."""
+    return (ak.at[blk, slot].set(k.transpose(1, 0, 2, 3).astype(ak.dtype),
+                                 mode="drop"),
+            av.at[blk, slot].set(v.transpose(1, 0, 2, 3).astype(av.dtype),
+                                 mode="drop"))
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _copy_block(ak, av, src, dst):
+    return ak.at[dst].set(ak[src]), av.at[dst].set(av[src])
+
+
+@functools.partial(jax.jit, static_argnames=("width",))
+def _gather_blocks(ak, av, table, n, *, width: int):
+    """Arena blocks ``table`` as positions 0..width of a B=1 cache array
+    (L, 1, width, KV, hd); positions from ``n`` on read zero."""
+    live = (jnp.arange(width) < n)[:, None, None, None]
+
+    def one(a):
+        x = a.at[table].get(mode="fill", fill_value=0)
+        x = x.reshape((-1,) + a.shape[2:])[:width]
+        return jnp.where(live, x, 0).transpose(1, 0, 2, 3)[:, None]
+
+    return one(ak), one(av)
+
+
 class PagedKVCache:
     """Block-granular KV arena with prefix sharing and LRU retention."""
 
     def __init__(self, n_blocks: int, block_tokens: int, *,
                  n_layers: int, n_kv_heads: int, head_dim: int,
+                 max_seq_tokens: Optional[int] = None, dtype=jnp.float32,
                  telemetry=None):
+        """``max_seq_tokens`` (default: the whole arena) bounds one
+        sequence and sets the width of every block table, and of the B=1
+        cache arrays :meth:`write_prompt_kv` takes and :meth:`gather`
+        returns; ``dtype`` is that cache's."""
         if n_blocks <= 0 or block_tokens <= 0:
             raise ValueError("n_blocks and block_tokens must be positive")
         self.n_blocks = n_blocks
         self.block_tokens = block_tokens
+        self.max_seq_tokens = int(max_seq_tokens or n_blocks * block_tokens)
+        self._table_len = -(-self.max_seq_tokens // block_tokens)
+        self.dtype = jnp.dtype(dtype)
         shape = (n_blocks, block_tokens, n_layers, n_kv_heads, head_dim)
-        self._k = np.zeros(shape, np.float32)
-        self._v = np.zeros(shape, np.float32)
+        self._k = jnp.zeros(shape, self.dtype)
+        self._v = jnp.zeros(shape, self.dtype)
         self._blocks = [_Block(i) for i in range(n_blocks)]
         self._free: list[int] = list(range(n_blocks - 1, -1, -1))
         self._index: dict[tuple, _Block] = {}
@@ -223,6 +296,9 @@ class PagedKVCache:
         tokens = tuple(int(t) for t in np.asarray(prompt_tokens).reshape(-1))
         if not tokens:
             raise ValueError("empty prompt")
+        if len(tokens) > self.max_seq_tokens:
+            raise ValueError(f"prompt of {len(tokens)} tokens exceeds "
+                             f"max_seq_tokens={self.max_seq_tokens}")
         seq = _Seq(rid=rid, sig=sig, prompt_len=len(tokens))
         seq.prompt_keys = prompt_chain_keys(sig, tokens, self.block_tokens)
         chunks = _chunk(tokens, self.block_tokens)
@@ -235,6 +311,7 @@ class PagedKVCache:
             self._touch(blk)
             seq.blocks.append(blk)
             shared += blk.filled
+        seq.n_shared_blocks = len(seq.blocks)
         for chunk in chunks[len(seq.blocks):]:
             try:
                 blk = self._alloc_block()
@@ -257,25 +334,35 @@ class PagedKVCache:
         for blk in seq.blocks:
             self._drop_ref(blk)
 
-    def write_prompt_kv(self, rid: int, ks: np.ndarray, vs: np.ndarray
-                        ) -> None:
-        """Write KV for the non-shared prompt suffix. ``ks``/``vs`` are
-        (n, L, KV, hd) with n == prompt_len - n_shared."""
-        seq = self._seqs[rid]
-        n = seq.prompt_len - seq.n_shared
-        if ks.shape[0] != n:
-            raise ValueError(f"expected {n} suffix tokens, got {ks.shape[0]}")
-        with phase("kv/write_prompt"):
-            self._write_at(seq, seq.n_shared, ks, vs)
+    def _table(self, blocks, lo: int = 0) -> np.ndarray:
+        """Block table of a chain, padded to the arena's table width with
+        the out-of-range index ``n_blocks``; entries before ``lo`` pad."""
+        table = np.full(self._table_len, self.n_blocks, np.int32)
+        for j in range(lo, len(blocks)):
+            table[j] = blocks[j].idx
+        return table
 
-    def _write_at(self, seq: _Seq, pos0: int, ks, vs) -> None:
-        bt = self.block_tokens
-        for i in range(ks.shape[0]):
-            pos = pos0 + i
-            blk = seq.blocks[pos // bt]
-            self._k[blk.idx, pos % bt] = np.asarray(ks[i], np.float32)
-            self._v[blk.idx, pos % bt] = np.asarray(vs[i], np.float32)
-            self._touch(blk)
+    def write_prompt_kv(self, rid: int, k, v) -> None:
+        """Write the KV of the prompt's private (non-shared) blocks from
+        the B=1 cache arrays ``k``, ``v`` (L, 1, S, KV, hd) that hold the
+        prompt at positions 0..prompt_len. Whole blocks are written;
+        positions past the prompt in its last block are overwritten by
+        later appends. Shared blocks are never rewritten."""
+        seq = self._seqs[rid]
+        S = k.shape[2]
+        if not seq.prompt_len <= S <= self._table_len * self.block_tokens:
+            raise ValueError(f"cache of {S} positions for a prompt of "
+                             f"{seq.prompt_len} tokens in an arena of "
+                             f"{self.max_seq_tokens}-token sequences")
+        with phase("kv/write_prompt"):
+            private = seq.blocks[seq.n_shared_blocks:]
+            if not private:
+                return
+            for blk in private:
+                self._touch(blk)
+            self._k, self._v = _write_blocks(
+                self._k, self._v, k, v,
+                self._table(seq.blocks, seq.n_shared_blocks))
 
     def _writable_last(self, seq: _Seq) -> _Block:
         """The sequence's last block, copy-on-write'd if shared. A block
@@ -288,53 +375,73 @@ class PagedKVCache:
         fresh = self._alloc_block()
         fresh.tokens = blk.tokens
         fresh.filled = blk.filled
-        self._k[fresh.idx] = self._k[blk.idx]
-        self._v[fresh.idx] = self._v[blk.idx]
+        self._k, self._v = _copy_block(self._k, self._v, np.int32(blk.idx),
+                                       np.int32(fresh.idx))
         self._drop_ref(blk)
         seq.blocks[-1] = fresh
         self.counters["cow_copies"] += 1
         self._m_cow.inc()
         return fresh
 
-    def append(self, rid: int, token: int, k: np.ndarray, v: np.ndarray
-               ) -> None:
-        """Append one generated token's KV. Allocates at block
-        boundaries; COWs a shared partial block before writing."""
-        with phase("kv/append"):
-            seq = self._seqs[rid]
-            bt = self.block_tokens
-            if seq.n_tokens % bt == 0:                 # boundary: new block
-                blk = self._alloc_block()
-                seq.blocks.append(blk)
-            else:
-                blk = self._writable_last(seq)
-            slot = seq.n_tokens % bt
-            self._k[blk.idx, slot] = np.asarray(k, np.float32)
-            self._v[blk.idx, slot] = np.asarray(v, np.float32)
-            blk.tokens = blk.tokens + (int(token),)
-            blk.filled += 1
-            seq.n_tokens += 1
-            self._touch(blk)
+    def _claim_slot(self, rid: int, token: int) -> tuple[int, int]:
+        """Book one more token for ``rid``: allocate at a block boundary,
+        COW a shared partial block. Returns the (block, slot) its KV
+        goes to."""
+        seq = self._seqs[rid]
+        bt = self.block_tokens
+        if seq.n_tokens % bt == 0:                 # boundary: new block
+            blk = self._alloc_block()
+            seq.blocks.append(blk)
+        else:
+            blk = self._writable_last(seq)
+        slot = seq.n_tokens % bt
+        blk.tokens = blk.tokens + (int(token),)
+        blk.filled += 1
+        seq.n_tokens += 1
+        self._touch(blk)
+        return blk.idx, slot
 
-    def gather(self, rid: int, n: Optional[int] = None
-               ) -> tuple[np.ndarray, np.ndarray]:
-        """KV for the sequence's first ``n`` tokens as (n, L, KV, hd)."""
+    def append(self, rids: list, tokens, k, v) -> list:
+        """Append one token's KV to each stream of a decode step: row b
+        of ``k``, ``v`` (L, B, KV, hd) is the KV of ``tokens[b]`` for
+        stream ``rids[b]``, or is dropped where ``rids[b]`` is None.
+        Returns the streams whose boundary allocation found the arena
+        full (:class:`KVCacheOOM`): their rows are dropped and nothing
+        of theirs changed, for the caller to release."""
+        if k.shape[1] != len(rids):
+            raise ValueError(f"{k.shape[1]} rows for {len(rids)} streams")
+        with phase("kv/append"):
+            blk = np.full(len(rids), self.n_blocks, np.int32)
+            slot = np.zeros(len(rids), np.int32)
+            oom = []
+            for b, rid in enumerate(rids):
+                if rid is None:
+                    continue
+                try:
+                    blk[b], slot[b] = self._claim_slot(rid, tokens[b])
+                except KVCacheOOM:
+                    oom.append(rid)
+            if (blk < self.n_blocks).any():
+                self._k, self._v = _append_rows(self._k, self._v, k, v,
+                                                blk, slot)
+            return oom
+
+    def _gather(self, blocks, n: int):
+        nb = -(-n // self.block_tokens)
+        return _gather_blocks(self._k, self._v, self._table(blocks[:nb]),
+                              np.int32(n), width=self.max_seq_tokens)
+
+    def gather(self, rid: int, n: Optional[int] = None):
+        """KV of the sequence's first ``n`` tokens (default: all) as B=1
+        cache arrays on the device, (L, 1, max_seq_tokens, KV, hd) at the
+        arena's dtype; positions from ``n`` on read zero."""
         with phase("kv/gather"):
             seq = self._seqs[rid]
             n = seq.n_tokens if n is None else n
-            bt = self.block_tokens
-            ks, vs, got = [], [], 0
-            for blk in seq.blocks:
-                if got >= n:
-                    break
-                take = min(blk.filled, bt, n - got)
-                ks.append(self._k[blk.idx, :take])
-                vs.append(self._v[blk.idx, :take])
-                got += take
-            if got < n:
+            if n > seq.n_tokens:
                 raise ValueError(f"sequence {rid}: asked {n} tokens, "
-                                 f"only {got} resident")
-            return np.concatenate(ks, axis=0), np.concatenate(vs, axis=0)
+                                 f"only {seq.n_tokens} resident")
+            return self._gather(seq.blocks, n)
 
     def finish(self, rid: int, *, retain: bool = True) -> None:
         """Complete a sequence. Prompt blocks whose content still matches
@@ -372,21 +479,36 @@ class PagedKVCache:
         payload carries the signature and the per-block token chunks —
         everything :func:`prompt_chain_keys` needs — so the importing
         arena indexes the blocks under the *identical* chain keys and
-        cross-request prefix sharing survives the hop. KV arrays are
-        copies: the exporting arena may evict or free the blocks the
-        moment the frame is on the wire."""
+        cross-request prefix sharing survives the hop. The blocks come
+        down from the device in one copy at the arena's dtype, which
+        ``d2h_bytes`` counts, and are widened to float32 host arrays: the
+        exporting arena may evict or free them the moment the frame is
+        on the wire."""
         seq = self._seqs[rid]
         chunks = _chunk(self._prompt_tokens(seq), self.block_tokens)
-        blocks = []
+        keep = []
         for i, blk in enumerate(seq.blocks[:len(chunks)]):
             if blk.tokens != chunks[i]:
                 break                 # diverged (post-prompt append): stop
-            blocks.append({"tokens": [int(t) for t in blk.tokens],
-                           "filled": int(blk.filled),
-                           "k": self._k[blk.idx, :blk.filled].copy(),
-                           "v": self._v[blk.idx, :blk.filled].copy()})
+            keep.append(blk)
+        n = sum(blk.filled for blk in keep)
+        blocks, nbytes = [], 0
+        if keep:
+            hk, hv = (np.asarray(x) for x in self._gather(seq.blocks, n))
+            nbytes = hk.nbytes + hv.nbytes
+            # (n, L, KV, hd): position p of the prompt is row p
+            rk, rv = (h[:, 0, :n].astype(np.float32).transpose(1, 0, 2, 3)
+                      for h in (hk, hv))
+            lo = 0
+            for blk in keep:
+                blocks.append({"tokens": [int(t) for t in blk.tokens],
+                               "filled": int(blk.filled),
+                               "k": rk[lo:lo + blk.filled],
+                               "v": rv[lo:lo + blk.filled]})
+                lo += blk.filled
         return {"sig": seq.sig, "block_tokens": self.block_tokens,
-                "prompt_len": seq.prompt_len, "blocks": blocks}
+                "prompt_len": seq.prompt_len, "blocks": blocks,
+                "d2h_bytes": nbytes}
 
     def import_prefix(self, sig: tuple, blocks: list) -> dict:
         """Seed the prefix index with exported prompt blocks. Each block
@@ -397,12 +519,18 @@ class PagedKVCache:
         aligned prefix. Chunks already indexed here are skipped (the
         affinity-routed case); an OOM mid-import keeps the contiguous
         prefix imported so far and stops — ``begin`` recomputes the tail,
-        degraded, never wrong. Returns counters for the caller's stats."""
+        degraded, never wrong. The new blocks go up to the device in one
+        write, cast to the arena's dtype (exact for a payload exported
+        from an arena of that dtype). Returns counters for the caller's
+        stats."""
         toks = tuple(int(t) for b in blocks for t in b["tokens"])
         keys = prompt_chain_keys(sig, toks, self.block_tokens)
         imported = reused = tokens_in = 0
         pinned: list = []             # chain blocks held until import ends
-        for key, b in zip(keys, blocks):
+        bt = self.block_tokens
+        table = np.full(self._table_len, self.n_blocks, np.int32)
+        hk = hv = None                # B=1 cache arrays of the new blocks
+        for j, (key, b) in enumerate(zip(keys[:self._table_len], blocks)):
             chunk = tuple(int(t) for t in b["tokens"])
             have = self._index.get(key)
             if have is not None and have.tokens == chunk:
@@ -415,9 +543,15 @@ class PagedKVCache:
                 blk = self._alloc_block()
             except KVCacheOOM:
                 break                 # chain keys need contiguity: stop
-            n = min(int(b["filled"]), self.block_tokens)
-            self._k[blk.idx, :n] = np.asarray(b["k"], np.float32)[:n]
-            self._v[blk.idx, :n] = np.asarray(b["v"], np.float32)[:n]
+            n = min(int(b["filled"]), bt)
+            if hk is None:
+                L, KV, hd = self._k.shape[2:]
+                hk, hv = (np.zeros((L, 1, self._table_len * bt, KV, hd),
+                                   self.dtype) for _ in range(2))
+            for h, x in ((hk, b["k"]), (hv, b["v"])):
+                h[:, 0, j * bt:j * bt + n] = np.asarray(x)[:n].transpose(
+                    1, 0, 2, 3)
+            table[j] = blk.idx
             blk.tokens = chunk
             blk.filled = n
             blk.ref = 1               # pinned while the import runs
@@ -426,6 +560,8 @@ class PagedKVCache:
             pinned.append(blk)
             imported += 1
             tokens_in += n
+        if imported:
+            self._k, self._v = _write_blocks(self._k, self._v, hk, hv, table)
         for blk in pinned:
             blk.ref -= 1              # land retained (ref 0, evictable)
         self.counters["handoff_blocks_in"] += imported

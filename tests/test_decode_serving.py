@@ -1,10 +1,10 @@
 """Decode serving: paged KV-cache invariants, iteration-level admission,
 and the continuous-batching server path staying numerically exact.
 
-The cache tests are pure numpy (no jax); the executor/server tests run
-the real decode path at smoke scale against the unbatched reference
-decoder — mid-decode admission must not perturb any resident stream's
-tokens.
+The cache tests drive the arena with small float32 arrays and read it
+back to the host; the executor/server tests run the real decode path at
+smoke scale against the unbatched reference decoder — mid-decode
+admission must not perturb any resident stream's tokens.
 """
 import numpy as np
 import pytest
@@ -26,6 +26,27 @@ def fake_kv(n, base=0.0):
     return np.broadcast_to(ks, (n, 1, 1, 2)).copy()
 
 
+def write(kv, rid, ks, vs):
+    """Write per-token rows (n, L, KV, hd) of the prompt from position 0
+    through the B=1 cache layout (L, 1, n, KV, hd) the arena takes."""
+    kv.write_prompt_kv(rid, ks.transpose(1, 0, 2, 3)[:, None],
+                       vs.transpose(1, 0, 2, 3)[:, None])
+
+
+def append(kv, rid, token, k, v):
+    """Append one token's KV (L, KV, hd) as a one-row decode step."""
+    row = (kv._k.shape[2], 1) + kv._k.shape[3:]
+    return kv.append([rid], [token], np.reshape(k, row), np.reshape(v, row))
+
+
+def gather(kv, rid, n=None):
+    """The arena's K and V of ``rid``'s first ``n`` tokens as host rows
+    (n, L, KV, hd)."""
+    n = kv.n_resident(rid) if n is None else n
+    return tuple(np.asarray(x)[:, 0, :n].transpose(1, 0, 2, 3)
+                 for x in kv.gather(rid, n))
+
+
 # ---------------------------------------------------------------- kv cache
 
 def test_begin_write_gather_roundtrip():
@@ -33,12 +54,12 @@ def test_begin_write_gather_roundtrip():
     toks = list(range(6))
     assert kv.begin(1, SIG, toks) == 0
     ks = fake_kv(6)
-    kv.write_prompt_kv(1, ks, ks * 10)
-    k, v = kv.gather(1)
+    write(kv, 1, ks, ks * 10)
+    k, v = gather(kv, 1)
     np.testing.assert_array_equal(k, ks)
     np.testing.assert_array_equal(v, ks * 10)
-    kv.append(1, 99, ks[0, 0] + 50, ks[0, 0] + 60)
-    k, _ = kv.gather(1)
+    append(kv, 1, 99, ks[0, 0] + 50, ks[0, 0] + 60)
+    k, _ = gather(kv, 1)
     assert k.shape[0] == 7 and k[-1, 0, 0, 0] == 50.0
 
 
@@ -79,7 +100,7 @@ def test_prefix_share_refcounts_full_blocks():
     toks = list(range(8))                        # 2 full blocks
     kv.begin(1, SIG, toks)
     ks = fake_kv(8)
-    kv.write_prompt_kv(1, ks, ks)
+    write(kv, 1, ks, ks)
     kv.finish(1, retain=True)                    # indexed, ref 0, resident
     assert kv.stats()["frees"] == 0
     shared = kv.begin(2, SIG, toks)
@@ -88,7 +109,7 @@ def test_prefix_share_refcounts_full_blocks():
     assert kv.stats()["prefix_tokens_reused"] == 8
     assert all(b.ref == 1 for b in kv._seqs[2].blocks)
     # the sharer's gather sees the donor's KV without any write
-    k, _ = kv.gather(2)
+    k, _ = gather(kv, 2)
     np.testing.assert_array_equal(k, ks)
     # a different-sig request must NOT match the same tokens
     assert kv.begin(3, ("m", 0, 99), toks) == 0
@@ -98,7 +119,7 @@ def test_partial_block_shares_only_exact_tail():
     kv = make_kv(n_blocks=8, bt=4)
     kv.begin(1, SIG, list(range(6)))             # 1 full + 1 partial
     ks = fake_kv(6)
-    kv.write_prompt_kv(1, ks, ks)
+    write(kv, 1, ks, ks)
     kv.finish(1, retain=True)
     # same full-block prefix but different tail: only the full block hits
     assert kv.begin(2, SIG, [0, 1, 2, 3, 9, 9]) == 4
@@ -112,28 +133,28 @@ def test_cow_on_shared_partial_block():
     toks = list(range(6))
     kv.begin(1, SIG, toks)
     ks = fake_kv(6)
-    kv.write_prompt_kv(1, ks, ks)
+    write(kv, 1, ks, ks)
     kv.finish(1, retain=True)                    # partial tail indexed "P"
     kv.begin(2, SIG, toks)                       # shares both blocks
     donor_tail = kv._seqs[2].blocks[-1]
     # appending into the shared partial block must copy it first
-    kv.append(2, 77, fake_kv(1)[0, 0] + 100, fake_kv(1)[0, 0])
+    append(kv, 2, 77, fake_kv(1)[0, 0] + 100, fake_kv(1)[0, 0])
     assert kv.counters["cow_copies"] == 1
     assert kv._seqs[2].blocks[-1] is not donor_tail
     # the donor's indexed block is untouched: a third request still
     # shares the full 6-token prefix, and its KV is the original
     assert kv.begin(3, SIG, toks) == 6
-    k3, _ = kv.gather(3, 6)
+    k3, _ = gather(kv, 3, 6)
     np.testing.assert_array_equal(k3, ks)
     # ...while the COW'd sequence sees its appended token privately
-    k2, _ = kv.gather(2)
+    k2, _ = gather(kv, 2)
     assert k2.shape[0] == 7 and k2[6, 0, 0, 0] == 100.0
 
 
 def test_lru_eviction_reclaims_retained_blocks():
     kv = make_kv(n_blocks=2, bt=4)
     kv.begin(1, SIG, list(range(8)))
-    kv.write_prompt_kv(1, fake_kv(8), fake_kv(8))
+    write(kv, 1, fake_kv(8), fake_kv(8))
     kv.finish(1, retain=True)                    # both blocks retained
     assert kv.n_free == 0
     # allocation pressure evicts the retained blocks instead of OOMing
@@ -151,14 +172,14 @@ def test_cow_and_eviction_interplay():
     toks = list(range(6))
     kv.begin(1, SIG, toks)
     ks = fake_kv(6)
-    kv.write_prompt_kv(1, ks, ks)
+    write(kv, 1, ks, ks)
     kv.finish(1, retain=True)
     kv.begin(2, SIG, toks)
-    kv.append(2, 7, fake_kv(1)[0, 0] + 100, fake_kv(1)[0, 0])   # COW
+    append(kv, 2, 7, fake_kv(1)[0, 0] + 100, fake_kv(1)[0, 0])   # COW
     # pressure: evict every retained block (donor's index entries)
     kv.begin(3, ("m", 1, 0), list(range(200, 208)))
     assert kv.stats()["evictions"] > 0
-    k2, _ = kv.gather(2)
+    k2, _ = gather(kv, 2)
     np.testing.assert_array_equal(k2[:6], ks)
     assert k2[6, 0, 0, 0] == 100.0
 
@@ -286,77 +307,148 @@ def _solo_pool(decode_pool, decode_ctx=32):
                             kv_blocks=32, kv_block_tokens=4)
 
 
+@pytest.fixture(scope="module")
+def bf16_pool(decode_pool):
+    """The fixture's model and pool at bfloat16: the dtype the served
+    models' caches hold, and so the arena's."""
+    import dataclasses
+
+    import jax
+
+    from repro import models as M
+    cfg, _, ex = decode_pool
+    cfg = dataclasses.replace(cfg, dtype="bfloat16")
+    params = jax.jit(M.init_params, static_argnums=1)(
+        jax.random.PRNGKey(0), cfg)
+    return cfg, params, ex
+
+
+def _bits(x) -> np.ndarray:
+    x = np.asarray(x)
+    return x.view(f"u{x.dtype.itemsize}")
+
+
 def _assert_arena_matches_dense(inst, rid):
     """The arena's K and V of stream ``rid`` are its dense cache row at
-    the same positions, widened to float32, bit for bit."""
+    the same positions, at the cache's own dtype, bit for bit."""
     slot = next(i for i, s in enumerate(inst._slots) if s and s["rid"] == rid)
-    ks, vs = inst.kv.gather(rid)
-    n = ks.shape[0]
-    for got, dense in ((ks, inst._dc["k"]), (vs, inst._dc["v"])):
-        want = np.asarray(dense[:, slot, :n], np.float32).transpose(1, 0, 2, 3)
-        assert got.dtype == np.float32
-        np.testing.assert_array_equal(got.view(np.uint32),
-                                      want.view(np.uint32))
+    n = inst.kv.n_resident(rid)
+    for got, dense in zip(inst.kv.gather(rid), (inst._dc["k"], inst._dc["v"])):
+        assert got.dtype == dense.dtype == inst.kv.dtype
+        np.testing.assert_array_equal(_bits(got[:, 0, :n]),
+                                      _bits(dense[:, slot, :n]))
+    return n
 
 
-def test_arena_holds_dense_cache_rows_exactly(decode_pool):
-    """Two streams, the second admitted mid-way through the first's
-    decode: after several steps the arena holds exactly what the dense
-    cache holds for each."""
-    cfg = decode_pool[0]
-    inst = _solo_pool(decode_pool)
+def test_arena_holds_dense_cache_rows_exactly(bf16_pool):
+    """Two cold admissions, the second mid-way through the first's
+    decode: after several steps the bf16 arena holds exactly what the
+    dense cache holds for each."""
+    cfg = bf16_pool[0]
+    inst = _solo_pool(bf16_pool)
     rng = np.random.RandomState(7)
     tA = rng.randint(0, cfg.vocab_size, 8).astype(np.int32)
     tB = rng.randint(0, cfg.vocab_size, 6).astype(np.int32)
     assert inst.decode_admit(401, "c0", tA, 12, sig=("e", 0, 0))["admitted"]
+    assert _assert_arena_matches_dense(inst, 401) == 8
     for _ in range(3):
         inst.decode_step_batch()
     assert inst.decode_admit(402, "c1", tB, 12, sig=("e", 0, 0))["admitted"]
+    assert _assert_arena_matches_dense(inst, 402) == 6
     for _ in range(4):
         inst.decode_step_batch()
-    assert inst.kv.gather(401)[0].shape[0] == 8 + 7
-    _assert_arena_matches_dense(inst, 401)
-    _assert_arena_matches_dense(inst, 402)
+    assert inst._dc["k"].dtype == np.dtype("bfloat16")
+    assert _assert_arena_matches_dense(inst, 401) == 8 + 7
+    assert _assert_arena_matches_dense(inst, 402) == 6 + 4
 
 
-def test_arena_exact_after_shared_prefix_admission(decode_pool):
-    """A prompt whose first blocks are already in the arena steps its
-    suffix through the B=1 step loop; the arena still holds exactly the
-    dense cache's row."""
-    cfg = decode_pool[0]
-    inst = _solo_pool(decode_pool)
+@pytest.mark.parametrize("tail", [3, 0], ids=["private_tail", "cow_tail"])
+def test_arena_exact_after_shared_prefix_admission(bf16_pool, tail):
+    """A prompt whose first blocks are already in the arena is seeded
+    from them on the device and steps the rest through the B=1 step
+    loop; the arena still holds exactly the dense cache's row. With no
+    tail the whole prompt is shared, its last block a donor's partial
+    block, which the first append copies (COW) before writing."""
+    cfg = bf16_pool[0]
+    inst = _solo_pool(bf16_pool)
     rng = np.random.RandomState(8)
-    head = rng.randint(0, cfg.vocab_size, 8).astype(np.int32)
-    tail = rng.randint(0, cfg.vocab_size, 3).astype(np.int32)
+    head = rng.randint(0, cfg.vocab_size, 8 if tail else 6).astype(np.int32)
+    toks = np.concatenate([head, rng.randint(0, cfg.vocab_size, tail)
+                           .astype(np.int32)])
     r0 = inst.decode_admit(501, "c0", head, 1, sig=("f", 0, 0))
     assert r0["admitted"] and r0["done"]          # retained in the arena
-    r1 = inst.decode_admit(502, "c0", np.concatenate([head, tail]), 6,
-                           sig=("f", 0, 0))
-    assert r1["admitted"] and r1["n_shared"] == 8
+    r1 = inst.decode_admit(502, "c0", toks, 6, sig=("f", 0, 0))
+    assert r1["admitted"] and r1["n_shared"] == len(head)
+    _assert_arena_matches_dense(inst, 502)
     for _ in range(3):
         inst.decode_step_batch()
-    _assert_arena_matches_dense(inst, 502)
+    assert inst.kv.counters["cow_copies"] == (0 if tail else 1)
+    assert _assert_arena_matches_dense(inst, 502) == len(toks) + 3
 
 
-def test_step_reads_written_rows_not_the_cache(decode_pool):
-    """A step reads back pos, its tokens and the K and V rows it wrote,
-    one position per slot, whatever the cache's length."""
-    cfg = decode_pool[0]
+def test_arena_programs_do_not_grow_with_prompt_length(bf16_pool):
+    """Admissions of three prompt lengths, cold and prefix-shared, add
+    no compiled arena program after the first of each kind: block
+    tables and positions are traced, never shapes."""
+    from repro.serving import executor, kvcache
+    fns = (kvcache._write_blocks, kvcache._append_rows,
+           kvcache._gather_blocks, executor._seed_prefix)
+    cfg = bf16_pool[0]
+    inst = _solo_pool(bf16_pool)
+    rng = np.random.RandomState(10)
+    sizes = None
+    for i, n in enumerate((5, 9, 14)):
+        toks = rng.randint(0, cfg.vocab_size, n).astype(np.int32)
+        assert inst.decode_admit(700 + i, "c0", toks, 1,
+                                 sig=("h", 0, 0))["done"]   # retained
+        # the same prompt and one more token: shares the full blocks
+        assert inst.decode_admit(710 + i, "c0", np.append(toks, 1), 2,
+                                 sig=("h", 0, 0))["n_shared"] > 0
+        inst.decode_step_batch()
+        now = [f._cache_size() for f in fns]
+        assert sizes is None or now == sizes, (n, sizes, now)
+        sizes = now
+
+
+def test_arena_append_donates_the_arena(bf16_pool):
+    """Each arena program updates in place: after a step's append the
+    arena's previous buffers are deleted, so no second arena is held."""
+    import jax
+    import jax.numpy as jnp
+    x = jnp.zeros(4)
+    jax.jit(lambda a: a + 1, donate_argnums=0)(x)
+    if not x.is_deleted():
+        pytest.skip("this backend does not donate buffers")
+    cfg = bf16_pool[0]
+    inst = _solo_pool(bf16_pool)
+    toks = np.random.RandomState(11).randint(0, cfg.vocab_size, 7)
+    assert inst.decode_admit(801, "c0", toks.astype(np.int32), 4,
+                             sig=("i", 0, 0))["admitted"]
+    k0, v0 = inst.kv._k, inst.kv._v
+    inst.decode_step_batch()
+    assert k0.is_deleted() and v0.is_deleted()
+    assert not inst.kv._k.is_deleted()
+
+
+@pytest.mark.parametrize("which", ["float32", "bfloat16"])
+def test_step_reads_written_rows_not_the_cache(decode_pool, bf16_pool,
+                                                which):
+    """A step copies only its tokens to the host, and an admission only
+    its first token, whatever the cache's length or dtype: the K and V
+    rows stay on the device, in the arena."""
+    pool = bf16_pool if which == "bfloat16" else decode_pool
+    cfg = pool[0]
     rng = np.random.RandomState(9)
     toks = rng.randint(0, cfg.vocab_size, 8).astype(np.int32)
-    per_step = []
     for ctx in (32, 64):
-        inst = _solo_pool(decode_pool, decode_ctx=ctx)
+        inst = _solo_pool(pool, decode_ctx=ctx)
         assert inst.decode_admit(601, "c0", toks, 4, sig=("g", 0, 0))[
             "admitted"]
+        assert inst.d2h_bytes == 4
         for _ in range(2):
             inst.decode_step_batch()
         B = len(inst._slots)
-        item = inst._dc["k"].dtype.itemsize
-        rows = cfg.n_layers * B * cfg.n_kv_heads * cfg.head_dim_ * item
-        assert inst.d2h_bytes == 2 * (B * 4 + B * 4 + 2 * rows)
-        per_step.append(inst.d2h_bytes // inst.decode_steps)
-    assert per_step[0] == per_step[1]
+        assert inst.d2h_bytes == 4 + 2 * B * 4
 
 
 def test_decode_abort_frees_slot_and_blocks(decode_pool):

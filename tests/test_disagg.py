@@ -122,8 +122,10 @@ SIG = ("m", 0, 7)
 
 def _prefill(kv, rid, toks, base=0.0):
     n_shared = kv.begin(rid, SIG, toks)
-    ks, vs = _fake_kv(len(toks) - n_shared, base)
-    kv.write_prompt_kv(rid, ks, vs)
+    ks, vs = _fake_kv(len(toks), base)
+    # per-token rows (n, L, KV, hd) -> the B=1 cache layout (L, 1, n, ...)
+    kv.write_prompt_kv(rid, ks.transpose(1, 0, 2, 3)[:, None],
+                       vs.transpose(1, 0, 2, 3)[:, None])
     return n_shared
 
 
@@ -168,7 +170,8 @@ def test_imported_partial_block_cows_on_append():
     shared_last = dst._seqs[2].blocks[-1]
     before = dst._k[shared_last.idx].copy()
     k1, v1 = _fake_kv(1, base=100.0)
-    dst.append(2, 99, k1[0], v1[0])
+    dst.append([2], [99], k1.transpose(1, 0, 2, 3),
+               v1.transpose(1, 0, 2, 3))
     assert dst.counters["cow_copies"] == 1
     assert dst._seqs[2].blocks[-1].idx != shared_last.idx
     np.testing.assert_array_equal(dst._k[shared_last.idx], before)
@@ -219,6 +222,66 @@ def test_kv_frame_validates_on_decode():
     bad["blocks"] = [dict(frame["blocks"][0], filled=99)]
     with pytest.raises(FrameError):
         decode_kv_blocks(bad)
+
+
+def test_executor_handoff_from_bf16_device_arena(smoke):
+    """Through the executor at bfloat16: a prefill pool's export copies
+    its device arena's blocks down and widens them to float32 (exactly
+    the bf16 rows); a second pool imports them, admits the prompt fully
+    shared, and decodes the same greedy tokens as a plain admission."""
+    import jax
+
+    from repro import models as M
+    from repro.serving.executor import FragmentInstance
+    from repro.serving.smoke import decode_plan
+    from repro.serving.transport import decode_kv_blocks
+    cfg, book, _ = smoke
+    spec = next(iter(plan_pools(decode_plan(cfg, book, _frags(cfg)))
+                     .values()))
+    cfg = dataclasses.replace(cfg, dtype="bfloat16")
+    params = jax.jit(M.init_params, static_argnums=1)(
+        jax.random.PRNGKey(0), cfg)
+
+    def pool():
+        return FragmentInstance(params, cfg, spec, decode_ctx=32,
+                                kv_blocks=32, kv_block_tokens=4)
+
+    def run(inst, handoff=None):
+        r = inst.decode_admit(1, "c0", toks, 6, sig=SIG, handoff=handoff)
+        assert r["admitted"]
+        # the prompt's K and V rows in the dense cache, (n, L, KV, hd)
+        r["rows"] = [np.asarray(inst._dc[x][:, 0, :len(toks)]).transpose(
+            1, 0, 2, 3) for x in "kv"]
+        out = [r["tok"]]
+        while inst.decode_active:
+            out += [ev["tok"] for ev in inst.decode_step_batch()["events"]]
+        return r, out
+
+    toks = np.random.RandomState(12).randint(0, cfg.vocab_size, 10).astype(
+        np.int32)
+    plain, want = run(pool())
+
+    src = pool()
+    exp = src.prefill_export(1, "c0", toks, SIG)
+    assert exp["exported"] and exp["tok"] == want[0]
+    assert src.kv.dtype == np.dtype("bfloat16")
+    handoff = decode_kv_blocks(exp["kv"])
+    k = np.concatenate([b["k"] for b in handoff["blocks"]])
+    v = np.concatenate([b["v"] for b in handoff["blocks"]])
+    assert k.dtype == v.dtype == np.float32 and k.shape[0] == len(toks)
+    # the cold prefill's cache row is the plain admission's: its bf16 rows
+    assert plain["rows"][0].dtype == np.dtype("bfloat16")
+    for got, rows in zip((k, v), plain["rows"]):
+        np.testing.assert_array_equal(got, rows.astype(np.float32))
+    # KV left the device once, at bf16; the token once more
+    assert src.d2h_bytes == 4 + 2 * src.kv.max_seq_tokens * np.prod(
+        k.shape[1:]) * 2
+
+    dst = pool()
+    r, got = run(dst, handoff)
+    assert r["n_shared"] == len(toks)
+    assert dst.kv.counters["handoff_blocks_in"] == len(handoff["blocks"])
+    assert got == want
 
 
 # -------------------------------------------------------------- serving
