@@ -21,10 +21,8 @@ def main(argv=None) -> None:
     from benchmarks import (bench_resource, bench_latency, bench_repartition,
                             bench_merging, bench_grouping, bench_throughput,
                             bench_massive, bench_overhead, bench_slo,
-                            bench_energy, bench_kernels, bench_incremental,
-                            bench_calibration, bench_controller,
-                            bench_transport, bench_server, bench_fleet,
-                            bench_decode)
+                            bench_energy, bench_incremental,
+                            bench_calibration, bench_controller)
     suites = {
         "calibration": bench_calibration.run, # Table 2 anchors
         "resource": bench_resource.run,       # Table 3 / Fig 7
@@ -37,15 +35,8 @@ def main(argv=None) -> None:
         "overhead": bench_overhead.run,       # Fig 19
         "slo": bench_slo.run,                 # Fig 20
         "energy": bench_energy.run,           # Fig 21
-        "kernels": bench_kernels.run,         # micro
         "incremental": bench_incremental.run, # paper §6 extension
         "controller": bench_controller.run,   # online control loop (beyond paper)
-        "transport": bench_transport.run,     # cross-process data path
-        "server": bench_server.run,           # event-driven serving runtime
-        "fleet": bench_fleet.run,             # multi-front-end scale-out
-        "router": bench_fleet.run_skew,       # weighted routing + stealing
-        "fleet_remote": bench_fleet.run_remote,  # per-FE worker channels
-        "decode": bench_decode.run,           # paged-KV continuous batching
     }
     only = set(args.only.split(",")) if args.only else None
     rows = Rows()

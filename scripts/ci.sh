@@ -1,17 +1,11 @@
 #!/usr/bin/env bash
-# Per-PR gate: tier-1 tests (minus slow subprocess compiles), a smoke of
-# the real-transport demo path, and a quick pass of the planner-latency
-# benches, so scheduler/controller/transport regressions surface before
-# merge.
+# Per-PR gate: tier-1 tests (minus slow subprocess compiles) and smokes
+# of the real-transport demo, decode, disagg and routing paths, so
+# scheduler/controller/transport regressions surface before merge. Speed
+# is measured on the chip (chipbench/), never here.
 #
-#   ./scripts/ci.sh                # full gate (tests + demo smoke + quick benches)
+#   ./scripts/ci.sh                # full gate (tests + smokes)
 #   ./scripts/ci.sh --tests        # tests only
-#   ./scripts/ci.sh --bench-gate   # quick benches -> BENCH_ci.json, fail on
-#                                  # >20% planner-latency / SLO-attainment
-#                                  # regression vs benchmarks/baseline.json
-#   ./scripts/ci.sh --write-baseline  # refresh benchmarks/baseline.json on a
-#                                  # quiet machine (run at the commit being
-#                                  # blessed, eyeball the diff, check it in)
 #   ./scripts/ci.sh --remote-smoke # multi-host-shaped serve loop: 2 front-ends
 #                                  # over the SOCKET executor (worker
 #                                  # subprocesses dialing back to
@@ -44,18 +38,6 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-
-if [[ "${1:-}" == "--write-baseline" ]]; then
-    python -m benchmarks.gate --write-baseline
-    exit $?
-fi
-
-if [[ "${1:-}" == "--bench-gate" ]]; then
-    python -m benchmarks.gate \
-        --only incremental,controller,transport,server,fleet,router,fleet_remote,kernels,decode \
-        --baseline benchmarks/baseline.json --out BENCH_ci.json
-    exit $?
-fi
 
 if [[ "${1:-}" == "--decode-smoke" ]]; then
     python - <<'EOF'
@@ -183,17 +165,4 @@ if [[ "${1:-}" != "--tests" ]]; then
     # the routing subsystem must keep stealing: wedge a front-end with
     # queued work, the survivor steals and completes it token-exact
     "$0" --route-smoke
-    # BLOCKING bench gate on the fast suites: planner latency, controller
-    # SLO attainment, the server_p99_ms serving-runtime tail, the
-    # ragged-execution keys (fragment_exec_ms / padding_waste_frac /
-    # recompile_count from the kernels + server packing rows), the
-    # decode keys (ttft_ms / tpot_ms / kv_block_util_frac), and the
-    # hot-client skew routing key (router_skew_p99_ms). The slow
-    # transport/fleet benches stay in the non-blocking --bench-gate job;
-    # missing non-gated baseline keys do not fail a subset run.
-    # Wider tolerance than the trend-tracking job: a blocking gate on a
-    # small shared runner must only trip on step-function regressions.
-    python -m benchmarks.gate --only incremental,controller,server,kernels,decode,router \
-        --tolerance 0.35 \
-        --baseline benchmarks/baseline.json --out BENCH_ci.json
 fi

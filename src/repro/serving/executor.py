@@ -103,17 +103,6 @@ def _sig_tuple(x):
     return x
 
 
-def _with_written_rows(out: tuple, pos) -> tuple:
-    """A decode step's ``(logits, cache)`` and the K and V rows it wrote:
-    ``cache[k][l, b, pos[b]]``, (L, B, KV, hd) each. Every row is
-    gathered, so the shape is fixed; the index clamps, as an empty
-    slot's position keeps advancing (its row is ignored)."""
-    logits, cache = out
-    B, Sc = cache["k"].shape[1], cache["k"].shape[2]
-    rows, at = jnp.arange(B), jnp.clip(pos, 0, Sc - 1)
-    return logits, cache, cache["k"][:, rows, at], cache["v"][:, rows, at]
-
-
 @jax.jit
 def _seed_prefix(c1: dict, k, v, pop):
     """B=1 cache ``c1`` holding the K and V gathered from the arena at
@@ -358,8 +347,7 @@ class FragmentInstance:
         self._slots = [None] * B
         cfg = self.cfg
         self._dstep = jax.jit(
-            lambda params, cache, toks: _with_written_rows(
-                decode_step(params, cfg, cache, toks), cache["pos"]))
+            lambda params, cache, toks: decode_step(params, cfg, cache, toks))
         ctx = self.decode_ctx
 
         # one program per prompt length, reused by every admission of that
@@ -406,7 +394,7 @@ class FragmentInstance:
                                   np.int32(pop))
                 logits = None
                 for t in toks[pop:]:
-                    logits, c1, _, _ = self._dstep(
+                    logits, c1 = self._dstep(
                         self._params, c1,
                         jnp.asarray([[int(t)]], jnp.int32))
             tok = jnp.argmax(logits[0, -1])
@@ -529,14 +517,12 @@ class FragmentInstance:
             toks = np.zeros((B, 1), np.int32)
             for i in active:
                 toks[i, 0] = self._slots[i]["last"]
-            logits, self._dc, k_dev, v_dev = self._call_counted(
+            logits, self._dc = self._call_counted(
                 self._dstep, self._params, self._dc, jnp.asarray(toks))
             nxt = jnp.argmax(logits[:, -1], axis=-1)
-        # the rows the step wrote, (L, B, KV, hd), go to the arena on the
-        # device; the host's bookkeeping overlaps the step
+        # the arena's bookkeeping overlaps the step
         oom = set(self.kv.append([s["rid"] if s else None
-                                  for s in self._slots],
-                                 toks[:, 0], k_dev, v_dev))
+                                  for s in self._slots], toks[:, 0]))
         with phase("decode/sync"):
             nxt.block_until_ready()
         with phase("decode/readback", bytes=nxt.nbytes):

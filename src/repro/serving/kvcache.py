@@ -2,7 +2,7 @@
 
 Each decode-capable stage pool owns ONE :class:`PagedKVCache`: a
 preallocated arena of fixed-size token blocks, held on the device, that
-backs the KV state of every request resident in that pool's continuous
+books the KV capacity of every request resident in that pool's continuous
 decode batch. The design is the vLLM paged-attention bookkeeping reduced
 to what the serving path needs:
 
@@ -22,22 +22,25 @@ to what the serving path needs:
   blocks stay allocated (refcount 0, indexed) as reuse candidates;
   allocation pressure evicts the least-recently-touched retained block
   before raising :class:`KVCacheOOM`. Eviction / hit / COW counters are
-  surfaced in pool stats and gated in the decode bench.
+  surfaced in pool stats.
 
-Storage and bookkeeping are split. K and V are two device arrays stacked
-over layers — ``(block, slot, layer, kv_head, head_dim)`` — at the dtype
-of the pool's dense decode cache (float32 by default), so the arena
-holds the cache's own values and nothing widens or narrows them. Every
-data operation is one jitted program of fixed shape that donates the
-arena, so it updates in place: a prompt's whole private blocks written
-from the B=1 prefill cache, one row per stream appended from a decode
-step's written rows, a block copied for copy-on-write, and a sequence's
-blocks gathered back into the B=1 cache layout. Block tables are padded
-to ``max_seq_tokens`` with out-of-range entries, which the scatter drops
-and the gather fills with zeros, so no program is traced per prompt
-length. Block chains, refcounts, the prefix index, LRU and COW decisions
-stay on the host. KV reaches the host only in :meth:`export_prefix`, the
-prefill side of a disaggregated handoff.
+The arena holds prompt KV, for prefix sharing and the disaggregated
+handoff, and books every stream's capacity in blocks; the pool's dense
+per-slot decode cache holds resident streams' KV, so a decode step
+writes no row here.
+
+K and V are two device arrays stacked over layers — ``(block, slot,
+layer, kv_head, head_dim)`` — at the dtype of the pool's dense decode
+cache, so nothing widens or narrows them. Every data operation is one
+jitted program of fixed shape: a prompt's private blocks written from the
+B=1 prefill cache and a block copied for copy-on-write, both donating the
+arena so it updates in place, and a sequence's prompt blocks gathered
+back into the B=1 cache layout. Block tables are padded to
+``max_seq_tokens`` with out-of-range entries, which the scatter drops and
+the gather fills with zeros, so no program is traced per prompt length.
+Block chains, refcounts, the prefix index, LRU and COW decisions stay on
+the host. KV reaches the host only in :meth:`export_prefix`, the prefill
+side of a disaggregated handoff.
 """
 from __future__ import annotations
 
@@ -140,16 +143,6 @@ def _write_blocks(ak, av, k, v, table):
     nb, bt = table.shape[0], ak.shape[1]
     return (ak.at[table].set(_cache_blocks(k, nb, bt, ak.dtype), mode="drop"),
             av.at[table].set(_cache_blocks(v, nb, bt, av.dtype), mode="drop"))
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _append_rows(ak, av, k, v, blk, slot):
-    """Row b of ``k``, ``v`` (L, B, KV, hd) into slot ``slot[b]`` of arena
-    block ``blk[b]``."""
-    return (ak.at[blk, slot].set(k.transpose(1, 0, 2, 3).astype(ak.dtype),
-                                 mode="drop"),
-            av.at[blk, slot].set(v.transpose(1, 0, 2, 3).astype(av.dtype),
-                                 mode="drop"))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -346,8 +339,8 @@ class PagedKVCache:
         """Write the KV of the prompt's private (non-shared) blocks from
         the B=1 cache arrays ``k``, ``v`` (L, 1, S, KV, hd) that hold the
         prompt at positions 0..prompt_len. Whole blocks are written;
-        positions past the prompt in its last block are overwritten by
-        later appends. Shared blocks are never rewritten."""
+        positions past the prompt in its last block are never read.
+        Shared blocks are never rewritten."""
         seq = self._seqs[rid]
         S = k.shape[2]
         if not seq.prompt_len <= S <= self._table_len * self.block_tokens:
@@ -383,47 +376,34 @@ class PagedKVCache:
         self._m_cow.inc()
         return fresh
 
-    def _claim_slot(self, rid: int, token: int) -> tuple[int, int]:
-        """Book one more token for ``rid``: allocate at a block boundary,
-        COW a shared partial block. Returns the (block, slot) its KV
-        goes to."""
-        seq = self._seqs[rid]
+    def append(self, rids: list, tokens) -> list:
+        """Book one more token for each stream of a decode step:
+        ``tokens[b]`` for stream ``rids[b]``, nothing where ``rids[b]`` is
+        None. A stream allocates at a block boundary and copies a shared
+        partial block (COW) first; its KV stays in the pool's dense cache.
+        Returns the streams whose allocation found the arena full
+        (:class:`KVCacheOOM`): nothing of theirs changed, for the caller
+        to release."""
         bt = self.block_tokens
-        if seq.n_tokens % bt == 0:                 # boundary: new block
-            blk = self._alloc_block()
-            seq.blocks.append(blk)
-        else:
-            blk = self._writable_last(seq)
-        slot = seq.n_tokens % bt
-        blk.tokens = blk.tokens + (int(token),)
-        blk.filled += 1
-        seq.n_tokens += 1
-        self._touch(blk)
-        return blk.idx, slot
-
-    def append(self, rids: list, tokens, k, v) -> list:
-        """Append one token's KV to each stream of a decode step: row b
-        of ``k``, ``v`` (L, B, KV, hd) is the KV of ``tokens[b]`` for
-        stream ``rids[b]``, or is dropped where ``rids[b]`` is None.
-        Returns the streams whose boundary allocation found the arena
-        full (:class:`KVCacheOOM`): their rows are dropped and nothing
-        of theirs changed, for the caller to release."""
-        if k.shape[1] != len(rids):
-            raise ValueError(f"{k.shape[1]} rows for {len(rids)} streams")
         with phase("kv/append"):
-            blk = np.full(len(rids), self.n_blocks, np.int32)
-            slot = np.zeros(len(rids), np.int32)
             oom = []
-            for b, rid in enumerate(rids):
+            for rid, token in zip(rids, tokens):
                 if rid is None:
                     continue
+                seq = self._seqs[rid]
                 try:
-                    blk[b], slot[b] = self._claim_slot(rid, tokens[b])
+                    if seq.n_tokens % bt == 0:         # boundary: new block
+                        blk = self._alloc_block()
+                        seq.blocks.append(blk)
+                    else:
+                        blk = self._writable_last(seq)
                 except KVCacheOOM:
                     oom.append(rid)
-            if (blk < self.n_blocks).any():
-                self._k, self._v = _append_rows(self._k, self._v, k, v,
-                                                blk, slot)
+                    continue
+                blk.tokens = blk.tokens + (int(token),)
+                blk.filled += 1
+                seq.n_tokens += 1
+                self._touch(blk)
             return oom
 
     def _gather(self, blocks, n: int):
@@ -432,15 +412,18 @@ class PagedKVCache:
                               np.int32(n), width=self.max_seq_tokens)
 
     def gather(self, rid: int, n: Optional[int] = None):
-        """KV of the sequence's first ``n`` tokens (default: all) as B=1
-        cache arrays on the device, (L, 1, max_seq_tokens, KV, hd) at the
-        arena's dtype; positions from ``n`` on read zero."""
+        """KV of the sequence's first ``n`` prompt tokens (default: the
+        whole prompt) as B=1 cache arrays on the device, (L, 1,
+        max_seq_tokens, KV, hd) at the arena's dtype; positions from ``n``
+        on read zero. The arena holds no decode row, so an ``n`` past the
+        prompt raises."""
         with phase("kv/gather"):
             seq = self._seqs[rid]
-            n = seq.n_tokens if n is None else n
-            if n > seq.n_tokens:
-                raise ValueError(f"sequence {rid}: asked {n} tokens, "
-                                 f"only {seq.n_tokens} resident")
+            n = seq.prompt_len if n is None else n
+            if n > seq.prompt_len:
+                raise ValueError(f"sequence {rid}: asked {n} tokens, the "
+                                 f"arena holds its {seq.prompt_len} prompt "
+                                 "tokens")
             return self._gather(seq.blocks, n)
 
     def finish(self, rid: int, *, retain: bool = True) -> None:

@@ -55,8 +55,7 @@ Because workers are keyed by pool identity ``(model, start, end)``,
 :meth:`RemoteExecutor.apply_plan` (inherited) keeps surviving workers —
 their pid, their compiled XLA program, their queue — alive across a
 replan; only genuinely new block ranges pay a process spawn + jax import
-+ trace/compile. That is the warm-instance story the plan-differ tells,
-now measurable in wall time (``benchmarks/bench_transport.py``).
++ trace/compile. That is the warm-instance story the plan-differ tells.
 """
 from __future__ import annotations
 
@@ -699,11 +698,6 @@ class RemoteExecutor(GraftExecutor):
     * ``launcher`` — a :class:`WorkerLauncher`, or a callable
       ``pool_key -> WorkerLauncher`` for heterogeneous placements (some
       pools local, some over ssh).
-    * ``per_frontend_channels`` — ``open_handle`` returns a dedicated
-      dial-back lane per caller (fleet front-ends overlap their uplink
-      transfers) instead of the shared deploy connection. On by default;
-      the off position is the shared-channel baseline
-      ``benchmarks/bench_fleet.py --remote`` compares against.
     * ``max_respawns`` / ``respawn_backoff_s`` — reconnect-with-backoff
       budget per worker; ``respawn_log`` records ``(key, gen)`` per
       recovery.
@@ -720,7 +714,6 @@ class RemoteExecutor(GraftExecutor):
                  transport: Optional[Transport] = None, *,
                  advertise_host: str = "127.0.0.1",
                  launcher: Union[WorkerLauncher, Callable, None] = None,
-                 per_frontend_channels: bool = True,
                  max_respawns: int = 3, respawn_backoff_s: float = 0.05,
                  packed: bool = True, telemetry=None,
                  beacon_interval_s: float = 0.0,
@@ -740,7 +733,6 @@ class RemoteExecutor(GraftExecutor):
         self.respawn_log: list = []             # (key, gen) per recovery
         self.advertise_host = advertise_host
         self._launcher = launcher
-        self.per_frontend_channels = per_frontend_channels
         self._max_respawns = max_respawns
         self._respawn_backoff_s = respawn_backoff_s
         # health beacons: per-worker poller threads ride a dedicated
@@ -841,10 +833,7 @@ class RemoteExecutor(GraftExecutor):
         """A dedicated dial-back lane to pool ``key``'s worker, so fleet
         front-ends' shaped uplink transfers overlap on separate TCP
         streams (the worker serializes actual execution on its pool
-        lock). With ``per_frontend_channels=False`` every caller shares
-        the one deploy connection — the pre-multi-channel behavior."""
-        if not self.per_frontend_channels:
-            return self._handles[key]
+        lock)."""
         w = self._workers[key]
         channel: Channel = w.open_channel()
         if self._shaper is not None:

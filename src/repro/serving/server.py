@@ -238,7 +238,6 @@ class GraftServer:
                  external_control: bool = False,
                  registry: Optional[dict] = None,
                  foreign_router: Optional[Callable] = None,
-                 decode_continuous: bool = True,
                  tpot_default_ms: float = 50.0,
                  telemetry=None):
         self.executor = executor
@@ -270,10 +269,6 @@ class GraftServer:
         # pending payload TOKENS reach the budget, so packed buffers stay
         # inside one compile bucket instead of growing with queue depth
         self.token_budget = max(int(token_budget), 0)
-        # decode serving: continuous admits new requests into a RUNNING
-        # decode batch at step boundaries; False degrades to the "waved"
-        # baseline (a new wave only starts once the batch fully drains)
-        self.decode_continuous = decode_continuous
         self.tpot_default_ms = float(tpot_default_ms)
         self._period_ms = getattr(controller, "control_period_ms", 250.0)
         self.waiting_grace_ms = waiting_grace_ms \
@@ -999,14 +994,11 @@ class GraftServer:
         held): pull queued admissions at the step boundary, advance every
         resident sequence one token, retire finished streams, and abort
         streams whose remaining tokens provably cannot meet the absolute
-        deadline (shed charge = remaining decode length). With
-        ``decode_continuous`` off this degrades to the waved baseline:
-        new admissions wait until the whole batch drains."""
+        deadline (shed charge = remaining decode length)."""
         handle = self._pool_handle(driver.key)
         foreign, items = None, []
         with phase("server/decode_tick"):
-            if driver.decode_free > 0 and (self.decode_continuous
-                                           or driver.decode_active == 0):
+            if driver.decode_free > 0:
                 items = driver.batcher.take(driver.decode_free)
         oneshot = [it for it in items if not it.decode]
         for it in items:

@@ -1,11 +1,10 @@
 """Shared scaffolding for real-execution (smoke-scale) serving runs.
 
-The executor tests, ``launch/serve.py --execute``, the online-serving
-example, and ``benchmarks/bench_transport.py`` all need the same setup:
-a reduced model config, a profile book built from its analytic layer
-costs, initialised parameters, and a fleet of smoke fragments whose
-partition points are valid for the reduced layer count. Centralised here
-so the pieces can't drift apart.
+The executor tests, ``launch/serve.py --execute`` and the online-serving
+example all need the same setup: a reduced model config, a profile book
+built from its analytic layer costs, initialised parameters, and a fleet
+of smoke fragments whose partition points are valid for the reduced
+layer count. Centralised here so the pieces can't drift apart.
 """
 from __future__ import annotations
 

@@ -33,16 +33,15 @@ def write(kv, rid, ks, vs):
                        vs.transpose(1, 0, 2, 3)[:, None])
 
 
-def append(kv, rid, token, k, v):
-    """Append one token's KV (L, KV, hd) as a one-row decode step."""
-    row = (kv._k.shape[2], 1) + kv._k.shape[3:]
-    return kv.append([rid], [token], np.reshape(k, row), np.reshape(v, row))
+def append(kv, rid, token):
+    """Book one token for ``rid`` as a one-stream decode step."""
+    return kv.append([rid], [token])
 
 
 def gather(kv, rid, n=None):
-    """The arena's K and V of ``rid``'s first ``n`` tokens as host rows
-    (n, L, KV, hd)."""
-    n = kv.n_resident(rid) if n is None else n
+    """The arena's K and V of ``rid``'s first ``n`` prompt tokens
+    (default: the whole prompt) as host rows (n, L, KV, hd)."""
+    n = kv._seqs[rid].prompt_len if n is None else n
     return tuple(np.asarray(x)[:, 0, :n].transpose(1, 0, 2, 3)
                  for x in kv.gather(rid, n))
 
@@ -58,9 +57,14 @@ def test_begin_write_gather_roundtrip():
     k, v = gather(kv, 1)
     np.testing.assert_array_equal(k, ks)
     np.testing.assert_array_equal(v, ks * 10)
-    append(kv, 1, 99, ks[0, 0] + 50, ks[0, 0] + 60)
+    # a decode token is booked, not stored: the prompt still reads back
+    # and a gather past it raises
+    assert append(kv, 1, 99) == []
+    assert kv.n_resident(1) == 7
     k, _ = gather(kv, 1)
-    assert k.shape[0] == 7 and k[-1, 0, 0, 0] == 50.0
+    np.testing.assert_array_equal(k, ks)
+    with pytest.raises(ValueError, match="prompt"):
+        kv.gather(1, 7)
 
 
 def test_double_free_raises():
@@ -138,7 +142,7 @@ def test_cow_on_shared_partial_block():
     kv.begin(2, SIG, toks)                       # shares both blocks
     donor_tail = kv._seqs[2].blocks[-1]
     # appending into the shared partial block must copy it first
-    append(kv, 2, 77, fake_kv(1)[0, 0] + 100, fake_kv(1)[0, 0])
+    append(kv, 2, 77)
     assert kv.counters["cow_copies"] == 1
     assert kv._seqs[2].blocks[-1] is not donor_tail
     # the donor's indexed block is untouched: a third request still
@@ -146,9 +150,10 @@ def test_cow_on_shared_partial_block():
     assert kv.begin(3, SIG, toks) == 6
     k3, _ = gather(kv, 3, 6)
     np.testing.assert_array_equal(k3, ks)
-    # ...while the COW'd sequence sees its appended token privately
-    k2, _ = gather(kv, 2)
-    assert k2.shape[0] == 7 and k2[6, 0, 0, 0] == 100.0
+    # ...and the COW'd private copy holds the same six prompt positions
+    k2, v2 = gather(kv, 2)
+    np.testing.assert_array_equal(k2, ks)
+    np.testing.assert_array_equal(v2, ks)
 
 
 def test_lru_eviction_reclaims_retained_blocks():
@@ -175,13 +180,14 @@ def test_cow_and_eviction_interplay():
     write(kv, 1, ks, ks)
     kv.finish(1, retain=True)
     kv.begin(2, SIG, toks)
-    append(kv, 2, 7, fake_kv(1)[0, 0] + 100, fake_kv(1)[0, 0])   # COW
+    append(kv, 2, 7)                             # COW
     # pressure: evict every retained block (donor's index entries)
     kv.begin(3, ("m", 1, 0), list(range(200, 208)))
     assert kv.stats()["evictions"] > 0
-    k2, _ = gather(kv, 2)
-    np.testing.assert_array_equal(k2[:6], ks)
-    assert k2[6, 0, 0, 0] == 100.0
+    # the COW'd block still holds the six prompt positions
+    k2, v2 = gather(kv, 2)
+    np.testing.assert_array_equal(k2, ks)
+    np.testing.assert_array_equal(v2, ks)
 
 
 def test_util_frac_and_has_room():
@@ -329,10 +335,11 @@ def _bits(x) -> np.ndarray:
 
 
 def _assert_arena_matches_dense(inst, rid):
-    """The arena's K and V of stream ``rid`` are its dense cache row at
-    the same positions, at the cache's own dtype, bit for bit."""
+    """The arena's K and V of stream ``rid``'s prompt are its dense
+    cache row at the same positions, at the cache's own dtype, bit for
+    bit. Returns the prompt's length."""
     slot = next(i for i, s in enumerate(inst._slots) if s and s["rid"] == rid)
-    n = inst.kv.n_resident(rid)
+    n = inst.kv._seqs[rid].prompt_len
     for got, dense in zip(inst.kv.gather(rid), (inst._dc["k"], inst._dc["v"])):
         assert got.dtype == dense.dtype == inst.kv.dtype
         np.testing.assert_array_equal(_bits(got[:, 0, :n]),
@@ -340,10 +347,19 @@ def _assert_arena_matches_dense(inst, rid):
     return n
 
 
+def _assert_no_decode_rows(inst, rid):
+    """The arena stores no decode row: a gather past the prompt of
+    stream ``rid``, which has decoded, raises."""
+    assert inst.kv.n_resident(rid) > inst.kv._seqs[rid].prompt_len
+    with pytest.raises(ValueError, match="prompt"):
+        inst.kv.gather(rid, inst.kv.n_resident(rid))
+
+
 def test_arena_holds_dense_cache_rows_exactly(bf16_pool):
     """Two cold admissions, the second mid-way through the first's
-    decode: after several steps the bf16 arena holds exactly what the
-    dense cache holds for each."""
+    decode: after several steps the bf16 arena still holds exactly what
+    the dense cache holds at each prompt's positions, and nothing past
+    them."""
     cfg = bf16_pool[0]
     inst = _solo_pool(bf16_pool)
     rng = np.random.RandomState(7)
@@ -358,17 +374,22 @@ def test_arena_holds_dense_cache_rows_exactly(bf16_pool):
     for _ in range(4):
         inst.decode_step_batch()
     assert inst._dc["k"].dtype == np.dtype("bfloat16")
-    assert _assert_arena_matches_dense(inst, 401) == 8 + 7
-    assert _assert_arena_matches_dense(inst, 402) == 6 + 4
+    assert _assert_arena_matches_dense(inst, 401) == 8
+    assert _assert_arena_matches_dense(inst, 402) == 6
+    assert inst.kv.n_resident(401) == 8 + 7
+    assert inst.kv.n_resident(402) == 6 + 4
+    _assert_no_decode_rows(inst, 401)
+    _assert_no_decode_rows(inst, 402)
 
 
 @pytest.mark.parametrize("tail", [3, 0], ids=["private_tail", "cow_tail"])
 def test_arena_exact_after_shared_prefix_admission(bf16_pool, tail):
     """A prompt whose first blocks are already in the arena is seeded
     from them on the device and steps the rest through the B=1 step
-    loop; the arena still holds exactly the dense cache's row. With no
-    tail the whole prompt is shared, its last block a donor's partial
-    block, which the first append copies (COW) before writing."""
+    loop; the arena still holds exactly the dense cache's row at the
+    prompt's positions. With no tail the whole prompt is shared, its last
+    block a donor's partial block, which the first append copies (COW)
+    before booking into it."""
     cfg = bf16_pool[0]
     inst = _solo_pool(bf16_pool)
     rng = np.random.RandomState(8)
@@ -383,7 +404,9 @@ def test_arena_exact_after_shared_prefix_admission(bf16_pool, tail):
     for _ in range(3):
         inst.decode_step_batch()
     assert inst.kv.counters["cow_copies"] == (0 if tail else 1)
-    assert _assert_arena_matches_dense(inst, 502) == len(toks) + 3
+    assert _assert_arena_matches_dense(inst, 502) == len(toks)
+    assert inst.kv.n_resident(502) == len(toks) + 3
+    _assert_no_decode_rows(inst, 502)
 
 
 def test_arena_programs_do_not_grow_with_prompt_length(bf16_pool):
@@ -391,8 +414,8 @@ def test_arena_programs_do_not_grow_with_prompt_length(bf16_pool):
     no compiled arena program after the first of each kind: block
     tables and positions are traced, never shapes."""
     from repro.serving import executor, kvcache
-    fns = (kvcache._write_blocks, kvcache._append_rows,
-           kvcache._gather_blocks, executor._seed_prefix)
+    fns = (kvcache._write_blocks, kvcache._gather_blocks,
+           executor._seed_prefix)
     cfg = bf16_pool[0]
     inst = _solo_pool(bf16_pool)
     rng = np.random.RandomState(10)
@@ -411,8 +434,10 @@ def test_arena_programs_do_not_grow_with_prompt_length(bf16_pool):
 
 
 def test_arena_append_donates_the_arena(bf16_pool):
-    """Each arena program updates in place: after a step's append the
-    arena's previous buffers are deleted, so no second arena is held."""
+    """An admission's prompt write updates the arena in place: the
+    arena's previous buffers are deleted, so no second arena is held. A
+    decode step then leaves the arena's buffers untouched: it writes no
+    row there."""
     import jax
     import jax.numpy as jnp
     x = jnp.zeros(4)
@@ -421,13 +446,16 @@ def test_arena_append_donates_the_arena(bf16_pool):
         pytest.skip("this backend does not donate buffers")
     cfg = bf16_pool[0]
     inst = _solo_pool(bf16_pool)
+    inst._ensure_decode()
+    k0, v0 = inst.kv._k, inst.kv._v
     toks = np.random.RandomState(11).randint(0, cfg.vocab_size, 7)
     assert inst.decode_admit(801, "c0", toks.astype(np.int32), 4,
                              sig=("i", 0, 0))["admitted"]
+    assert k0.is_deleted() and v0.is_deleted()
     k0, v0 = inst.kv._k, inst.kv._v
     inst.decode_step_batch()
-    assert k0.is_deleted() and v0.is_deleted()
-    assert not inst.kv._k.is_deleted()
+    assert inst.kv._k is k0 and inst.kv._v is v0
+    assert not k0.is_deleted()
 
 
 @pytest.mark.parametrize("which", ["float32", "bfloat16"])
@@ -435,7 +463,7 @@ def test_step_reads_written_rows_not_the_cache(decode_pool, bf16_pool,
                                                 which):
     """A step copies only its tokens to the host, and an admission only
     its first token, whatever the cache's length or dtype: the K and V
-    rows stay on the device, in the arena."""
+    rows stay on the device."""
     pool = bf16_pool if which == "bfloat16" else decode_pool
     cfg = pool[0]
     rng = np.random.RandomState(9)

@@ -1,8 +1,6 @@
 """Ragged (sequence-packed) fragment execution and its satellite fixes:
 per-request extras stacking, honest compile counting, phantom-uplink
 skipping, and the bucket/packing layout policies."""
-import os
-import sys
 
 import jax
 import numpy as np
@@ -378,29 +376,33 @@ def test_packed_equals_per_request_random_mixes(dense):
     prop()
 
 
-# ------------------------------------------------------- bench smoke
+# ------------------------------------------------ packed vs padded traffic
 
-def test_bench_fragment_smoke():
-    """Tier-1 smoke: one packed mixed-length pass through the real
-    fragment bench — kernel-wiring breakage fails here, not in the slow
-    bench job."""
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    from benchmarks.bench_kernels import run_fragment
-    from benchmarks.common import Rows
-
-    rows = Rows()
-    run_fragment(rows, quick=True)
-    by_name = {n: (us, d) for n, us, d in rows.rows}
-    assert "kernels/fragment/packed" in by_name
-    assert "kernels/fragment/padded" in by_name
-
-    def field(name, key):
-        d = dict(kv.split("=") for kv in by_name[name][1].split(";"))
-        return float(d[key])
-
-    # the tentpole's acceptance: packing strictly reduces padding waste
-    # and compile churn on the same ragged traffic
-    assert field("kernels/fragment/packed", "padding_waste_frac") < \
-        field("kernels/fragment/padded", "padding_waste_frac")
-    assert field("kernels/fragment/packed", "recompile_count") < \
-        field("kernels/fragment/padded", "recompile_count")
+def test_packed_beats_padded_on_ragged_mixes(dense):
+    """The same seeded ragged mixes through a packed and a pad-to-bucket
+    pool: packing executes fewer pad tokens and compiles fewer programs,
+    with the same outputs."""
+    cfg, params = dense
+    L = M.n_fragment_units(cfg)
+    mixes_rng = np.random.RandomState(7)
+    mixes = [[int(mixes_rng.choice((8, 12, 16, 24)))
+              for _ in range(mixes_rng.randint(1, 5))] for _ in range(4)]
+    pools, outs = {}, {}
+    for packed in (False, True):
+        rng = np.random.RandomState(8)              # identical traffic
+        inst = pools[packed] = FragmentInstance(
+            params, cfg, _spec(cfg, 0, L), packed=packed)
+        out = outs[packed] = {}
+        for r, mix in enumerate(mixes):
+            for i, S in enumerate(mix):
+                req = ServeRequest(client=f"c{r}.{i}",
+                                   tokens=_tokens(rng, cfg, S))
+                inst.submit(req, req.tokens)
+            out.update((req.client, np.asarray(y)) for req, y in inst.flush())
+    assert pools[True].pad_tokens < pools[False].pad_tokens
+    assert pools[True].n_compiles < pools[False].n_compiles
+    assert pools[True].real_tokens == pools[False].real_tokens
+    assert outs[True].keys() == outs[False].keys()
+    for client, y in outs[True].items():
+        np.testing.assert_allclose(y, outs[False][client], atol=ATOL,
+                                   rtol=RTOL)

@@ -374,7 +374,7 @@ def test_decode_path_writes_its_phases(decode_profile):
             "kv/write_prompt", "transport/pack", "transport/unpack",
             "server/decode_tick", "server/decode_admit"} <= set(names)
     # K and V stay on the device: a step copies its tokens down once and
-    # widens nothing, and hands its K and V rows to the arena once
+    # widens nothing, and books its tokens in the arena once
     assert "decode/widen" not in names
     steps = names.count("decode/dispatch")
     assert steps >= 3
